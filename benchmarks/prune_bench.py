@@ -213,7 +213,12 @@ def _mesh_gram_child(devices: int) -> Dict:
 def bench_mesh_gram(device_counts=(1, 8)) -> Dict:
     """Parent-side: spawn one child per device count (XLA fake-device
     flags must be set before jax initializes, hence subprocesses) and
-    assemble the comparison row for BENCH_prune.json."""
+    assemble the comparison row for BENCH_prune.json.
+
+    The children run on fake CPU host devices, always: their row holds
+    CPU counts (dispatches, scan steps), not chip numbers.  The caller
+    must not have touched a JAX device yet — a process that holds the
+    chip keeps it from every child."""
     from repro.utils.compat import force_host_devices_flags
 
     rows = []
@@ -223,7 +228,7 @@ def bench_mesh_gram(device_counts=(1, 8)) -> Dict:
         # last duplicated XLA flag wins, so an exported =8 would
         # override the child's count
         env["XLA_FLAGS"] = force_host_devices_flags(n)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"
         out = subprocess.run(
             [sys.executable, "-m", "benchmarks.prune_bench",
              "--mesh-gram-child", str(n)],
@@ -233,23 +238,25 @@ def bench_mesh_gram(device_counts=(1, 8)) -> Dict:
                                f"{out.stdout}\n{out.stderr}")
         row = json.loads(out.stdout.splitlines()[-1])
         rows.append(row)
-        print(f"{n:>2} device(s): {row['gram_dispatches']} Gram dispatches, "
+        print(f"{n:>2} fake CPU device(s): {row['gram_dispatches']} Gram "
+              f"dispatches, "
               f"{row['scan_steps_per_device']} scan step(s)/device "
               f"({row['calib_batches']} calib batches)")
     base = rows[0]
-    return {"rows": rows,
+    return {"backend": "cpu", "rows": rows,
             "scan_step_ratio": base["scan_steps_per_device"]
             / max(rows[-1]["scan_steps_per_device"], 1)}
 
 
 def run_all(out_path: str = OUT_PATH) -> List[Dict]:
+    # the CPU children first, while this process holds no device
+    print("\n== Mesh-native Gram accumulation (1 vs 8 fake CPU devices) ==")
+    mesh_gram = bench_mesh_gram()
     print("\n== Prune solver bench (host vs fused vs group-batched) ==")
     rows = bench_prune_impls()
     print("\n== Per-solver matrix (fista / admm / frankwolfe / wanda /"
           " sparsegpt) ==")
     matrix = bench_solver_matrix()
-    print("\n== Mesh-native Gram accumulation (1 vs 8 fake devices) ==")
-    mesh_gram = bench_mesh_gram()
     summary = _summarize(rows)
     payload = {"rows": rows, "solver_matrix": matrix, "summary": summary,
                "mesh_gram": mesh_gram, "backend": jax.default_backend()}

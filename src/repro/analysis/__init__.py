@@ -7,7 +7,7 @@ actually shipped and reverted:
                    reuse, hot-path host syncs, undeclared jit caches.
 * ``rules_pallas`` PAL001-PAL004: BlockSpec index-map bounds, VMEM
                    budgets, tile alignment, oracle + dispatch gates.
-* ``rules_mesh``   MESH001-MESH002: explicit shard_map check_rep,
+* ``rules_mesh``   MESH001-MESH002: explicit shard_map check_vma,
                    replicate-before-sample domination.
 * ``rules_obs``    OBS001: obs recording calls inside jitted function
                    bodies or hot-path loop bodies.

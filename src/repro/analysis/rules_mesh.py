@@ -1,9 +1,9 @@
 """Sharding audit (rule family MESH, DESIGN.md §12).
 
-MESH001  Every ``shard_map`` call must pass ``check_rep`` explicitly.
-         The default flipped behavior across jax versions and silently
-         governs whether replication invariants of the body are
-         verified; mesh code must say which contract it relies on.
+MESH001  Every ``shard_map`` call must pass ``check_vma`` explicitly.
+         It silently governs whether the varying-manifest-axes
+         (replication) invariants of the body are verified; mesh code
+         must say which contract it relies on.
 MESH002  A sampling call (``jax.random.categorical`` or
          ``sampling.sample``) must be *dominated* by a
          ``replicate_logits`` rebinding of its logits operand in the
@@ -27,7 +27,7 @@ _SAMPLING_FNS = {"sample"}          # repro.serve.sampling.sample
 _REPLICATORS = {"replicate_logits"}
 
 
-def check_shard_map_check_rep(ctx: ModuleCtx) -> List[Finding]:
+def check_shard_map_check_vma(ctx: ModuleCtx) -> List[Finding]:
     findings: List[Finding] = []
     for node in ast.walk(ctx.tree):
         if not isinstance(node, ast.Call):
@@ -35,12 +35,12 @@ def check_shard_map_check_rep(ctx: ModuleCtx) -> List[Finding]:
         fname = dotted_name(node.func)
         if fname.split(".")[-1] != "shard_map":
             continue
-        if any(kw.arg == "check_rep" for kw in node.keywords):
+        if any(kw.arg == "check_vma" for kw in node.keywords):
             continue
         findings.append(Finding(
             rule="MESH001", path=ctx.rel, line=node.lineno,
             context="", detail=unparse(node, 50),
-            message="shard_map without explicit check_rep= — declare the "
+            message="shard_map without explicit check_vma= — declare the "
                     "replication contract the body relies on"))
     return findings
 
@@ -147,4 +147,4 @@ def check_sampling_replicated(ctx: ModuleCtx) -> List[Finding]:
 
 
 def check_module(ctx: ModuleCtx) -> List[Finding]:
-    return check_shard_map_check_rep(ctx) + check_sampling_replicated(ctx)
+    return check_shard_map_check_vma(ctx) + check_sampling_replicated(ctx)

@@ -347,19 +347,12 @@ def _build_paged() -> None:
     import jax.numpy as jnp
     from repro.kernels import paged_attention as mod
     S, nq, nkv, hd, bs, nblocks = 2, 8, 2, 128, 8, 8
-    g = nq // nkv
     q = jnp.zeros((S, nq, hd), jnp.float32)
     pool = jnp.zeros((nblocks * bs, nkv, hd), jnp.float32)
     tables = jnp.asarray([[0, 1, 2, 7], [3, 4, 5, 6]], jnp.int32)
     pos = jnp.asarray([5, 9], jnp.int32)
     active = jnp.asarray([1, 1], jnp.int32)
     mod.paged_decode_attn(q, pool, pool, tables, pos, active, block_size=bs)
-    d = 256
-    wo_vals = jnp.zeros((d, nq * hd // 2), jnp.float32)
-    wo_meta = jnp.zeros((d, nq * hd // 4), jnp.uint8)
-    mod.paged_decode_attn(q, pool, pool, tables, pos, active, block_size=bs,
-                          wo_vals=wo_vals, wo_meta=wo_meta)
-    del g
 
 
 def _build_fused_mlp() -> None:

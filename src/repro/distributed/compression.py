@@ -15,7 +15,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize(g: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -66,10 +65,10 @@ def compressed_allreduce(mesh: Mesh, grads: Any, residuals: Any,
                                  qs.astype(jnp.float32), axes=1) / axis_size
             return mean[None], new_r[None]
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(P(data_axis), P(data_axis)),
-                       out_specs=(P(data_axis), P(data_axis)),
-                       check_rep=True)  # MESH001: explicit contract
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P(data_axis), P(data_axis)),
+                           out_specs=(P(data_axis), P(data_axis)),
+                           check_vma=True)  # MESH001: explicit contract
         mean, new_r = fn(g, r)
         return mean, new_r
 

@@ -18,7 +18,8 @@ owner of mesh construction and placement that all three now share
 * **serve** — params place onto the mesh via the Megatron rules in
   ``distributed/sharding.py`` (column/row per block -> one all-reduce
   per block in decode) and the paged KV pool gains a heads-sharded
-  device layout; GSPMD partitions the jitted decode step.
+  device layout; GSPMD partitions the jitted decode step (the mesh's
+  axes are Auto: ``launch/mesh.make_mesh``).
 
 Determinism contract: XLA's CPU all-reduce is an ordered linear
 reduction over the axis, so with one micro-batch per data shard the
@@ -37,7 +38,6 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
@@ -137,8 +137,9 @@ class MeshExecutor:
         if mesh is not None:
             self.mesh = mesh
         else:
+            from repro.launch.mesh import make_mesh
             data, model = cfg.resolve()
-            self.mesh = jax.make_mesh((data, model), ("data", "model"))
+            self.mesh = make_mesh((data, model), ("data", "model"))
         self.data_size = int(self.mesh.shape["data"])
         self.model_size = int(self.mesh.shape["model"])
         # jitted shard_map closures, keyed by call site: a fresh closure
@@ -269,12 +270,11 @@ class MeshExecutor:
 
             # prefix specs (structure-independent, so the jitted closure
             # is reusable across shape buckets of the same group)
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P(), P(), P(), P(), P("data"), P("data")),
                 out_specs=P(),
-                check_rep=False))  # psum outputs are replicated; jit-
-            # inside-shard_map scans carry no rep annotations on 0.4.x
+                check_vma=False))  # psum outputs are replicated
 
         fn = self._cached(
             ("gram", scan_fn,
@@ -337,11 +337,11 @@ class MeshExecutor:
                 _, ys = jax.lax.scan(body, None, st)
                 return ys
 
-            return jax.jit(shard_map(
+            return jax.jit(jax.shard_map(
                 local, mesh=self.mesh,
                 in_specs=(P("data"),) + (P(),) * len(params),
                 out_specs=P("data"),
-                check_rep=False))
+                check_vma=False))
 
         mapped = build() if cache_key is None else \
             self._cached(("map", cache_key, len(params)), build)

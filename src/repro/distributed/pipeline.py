@@ -20,9 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.utils import compat
 
 from repro.utils.tree import tree_map_with_path
 
@@ -57,8 +55,8 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarra
         idx = jax.lax.axis_index(axis)
         # initial carries must be marked pod-varying: they mix with idx-
         # dependent values inside the loop (shard_map vma typing)
-        zero = compat.pvary(jnp.zeros_like(xs_local[0]), (axis,))
-        outputs = compat.pvary(jnp.zeros_like(xs_local), (axis,))
+        zero = jax.lax.pvary(jnp.zeros_like(xs_local[0]), (axis,))
+        outputs = jax.lax.pvary(jnp.zeros_like(xs_local), (axis,))
 
         def tick(t, state):
             carry, outputs = state
@@ -83,8 +81,8 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarra
         return jax.lax.psum(outputs * mask, axis)
 
     in_specs = (tree_map_with_path(lambda p, l: P(axis), stage_params), P())
-    fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=True)  # MESH001: explicit contract
+    fn = jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(), check_vma=True)  # MESH001: explicit contract
     return fn(stage_params, xs)
 
 

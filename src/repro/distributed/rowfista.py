@@ -16,7 +16,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import fista as fista_lib
 from repro.core import gram as gram_lib
@@ -45,10 +44,10 @@ def sharded_solve(mesh: Mesh, G: jnp.ndarray, B: jnp.ndarray, y0: jnp.ndarray,
                                  step_impl=step_impl)
         return out
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(None, None), P(axis, None), P(axis, None)),
-                   out_specs=P(axis, None),
-                   check_rep=False)  # no replication rule for while_loop
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, None), P(axis, None), P(axis, None)),
+                       out_specs=P(axis, None),
+                       check_vma=False)  # no replication rule for while_loop
     return fn(G, B.astype(jnp.float32), y0.astype(jnp.float32))
 
 
@@ -70,12 +69,12 @@ def sharded_accumulate(mesh: Mesh, stats: GramStats, x_dense: jnp.ndarray,
         dn = jax.lax.psum(jnp.float32(xd.shape[0]), data_axis)
         return G + dG, C + dC, H + dH, h + dh, cnt + dn
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None), P(None, None), P(None, None), P(), P(),
                   P(data_axis), P(data_axis), P(data_axis)),
         out_specs=(P(None, None), P(None, None), P(None, None), P(), P()),
-        check_rep=True)  # MESH001: explicit contract
+        check_vma=True)  # MESH001: explicit contract
     G, C, H, h, cnt = fn(stats.G, stats.C, stats.H, stats.h, stats.count,
                          x_dense, x_pruned, wx_dense)
     return GramStats(G=G, C=C, H=H, h=h, count=cnt)

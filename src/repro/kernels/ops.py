@@ -31,6 +31,7 @@ def _interpret() -> bool:
 
 
 _MIN_PALLAS_DIM = 128  # below this, use the jnp oracle
+_FUSED_MLP_BF = 512    # d_ff tile: keeps the (d, bf/4) meta block lane-aligned
 
 
 def fista_prox_step(y: jnp.ndarray, G: jnp.ndarray, B: jnp.ndarray,
@@ -49,8 +50,16 @@ def round24(w: jnp.ndarray) -> jnp.ndarray:
     return _round24.round24(w, interpret=_interpret())
 
 
+def use_spmm24(m: int, n: int) -> bool:
+    """True when ``spmm24`` compiles for a packed (m, n) weight without
+    padding it: TPU backend, whole 128-row rebuild chunks, and an input
+    tile that keeps the meta block lane-aligned (``spmm24.k_tile``)."""
+    return (not _interpret()) and m % _spmm24.LANES == 0 \
+        and n >= 2 * _MIN_PALLAS_DIM and _spmm24.k_tile(n) is not None
+
+
 def spmm24(x: jnp.ndarray, vals: jnp.ndarray, meta: jnp.ndarray, n: int) -> jnp.ndarray:
-    if _interpret() or vals.shape[0] < _MIN_PALLAS_DIM or n < 2 * _MIN_PALLAS_DIM:
+    if not use_spmm24(vals.shape[0], n):
         return ref.spmm24(x, vals, meta, n)
     return _spmm24.spmm24(x, vals, meta, n, interpret=False)
 
@@ -73,34 +82,26 @@ def use_decode_kernel(head_dim: int, block_size: int) -> bool:
 
 
 def paged_decode_attn(q, k_pool, v_pool, tables, pos, active, *,
-                      block_size: int, window: int = 0, softcap: float = 0.0,
-                      wo_vals=None, wo_meta=None):
-    """Block-table flash decode (+ optional packed o_proj epilogue).
+                      block_size: int, window: int = 0, softcap: float = 0.0):
+    """Block-table flash decode -> (S, nq, hd) in q.dtype.
 
     Kernel on TPU-compilable shapes, ``ref.paged_attention`` otherwise.
-    Without ``wo_vals`` returns (S, nq, hd) in q.dtype; with it, the
-    projected (S, d_model) in float32 (caller casts).
     """
     if not use_decode_kernel(q.shape[-1], block_size):
-        out = ref.paged_attention(q, k_pool, v_pool, tables, pos, active,
-                                  block_size=block_size, window=window,
-                                  softcap=softcap)
-        if wo_vals is None:
-            return out
-        S, nq, hd = q.shape
-        return ref.spmm24(out.reshape(S, nq * hd).astype(jnp.float32),
-                          wo_vals.astype(jnp.float32), wo_meta, nq * hd)
+        return ref.paged_attention(q, k_pool, v_pool, tables, pos, active,
+                                   block_size=block_size, window=window,
+                                   softcap=softcap)
     return _paged.paged_decode_attn(q, k_pool, v_pool, tables, pos, active,
                                     block_size=block_size, window=window,
-                                    softcap=softcap, wo_vals=wo_vals,
-                                    wo_meta=wo_meta, interpret=False)
+                                    softcap=softcap, interpret=False)
 
 
 def use_fused_mlp(d_model: int, d_ff: int) -> bool:
-    """True when ``fused_mlp24`` compiles for these dims (TPU, tiles wide
-    enough for the MXU); same fallback contract as ``use_decode_kernel``."""
-    return (not _interpret()) and d_model >= _MIN_PALLAS_DIM \
-        and d_ff >= 2 * _MIN_PALLAS_DIM
+    """True when ``fused_mlp24`` compiles for these dims without padding:
+    TPU, whole 128-row rebuild chunks of the down projection, and whole
+    d_ff tiles; same fallback contract as ``use_decode_kernel``."""
+    return (not _interpret()) and d_model % _spmm24.LANES == 0 \
+        and d_ff % _FUSED_MLP_BF == 0
 
 
 def fused_mlp24(x, w1_vals, w1_meta, b1, up_vals, up_meta, w2_vals, w2_meta,
@@ -113,7 +114,8 @@ def fused_mlp24(x, w1_vals, w1_meta, b1, up_vals, up_meta, w2_vals, w2_meta,
         return ref.fused_mlp24(x, w1_vals, w1_meta, b1, up_vals, up_meta,
                                w2_vals, w2_meta, b2, act=act)
     return _paged.fused_mlp24(x, w1_vals, w1_meta, b1, up_vals, up_meta,
-                              w2_vals, w2_meta, b2, act=act, interpret=False)
+                              w2_vals, w2_meta, b2, act=act,
+                              bf=_FUSED_MLP_BF, interpret=False)
 
 
 # ---------------------------------------------------------------------------

@@ -10,27 +10,22 @@ j-th context block of slot ``s`` straight out of the pool, while the
 per-slot length mask, the sliding-window cut and the active-slot mask
 fold into the online-softmax accumulator.
 
-Grid layout (``paged_decode_attn``): ``(S, nkv, MB)`` — serving slot x
-kv head x table column, table column innermost so the (m, l, acc)
-scratch carries the online-softmax state across one slot-head's context
-blocks, exactly like ``flash_attention.py`` carries it across k-blocks.
-GQA is grid-native: each step loads the ``g = nq/nkv`` query heads of
-one kv head, so repeated KV heads are never materialized and a
-tensor-parallel mesh can map the head axis onto the grid by sharding
-``nkv`` (see ``models/common._paged_attn_sharded``).
+Grid layout (``paged_decode_attn``): ``(S, MB)`` — serving slot x table
+column, table column innermost so the per-head (m, l, acc) scratch
+carries the online-softmax state across one slot's context blocks,
+exactly like ``flash_attention.py`` carries it across k-blocks.  Each
+step DMAs one pool block with all its kv heads — a (block_size, nkv, hd)
+tile, whose last two dims are whole pool dims as the TPU tiling rule
+asks — and loops over the kv heads inside the kernel.  GQA is
+grid-native: head ``h`` attends with its ``g = nq/nkv`` query heads, so
+repeated KV heads are never materialized, and a tensor-parallel mesh
+shards ``nkv`` before the call (see ``models/common._paged_attn_sharded``).
 
-Two fused epilogues consume the packed-2:4 store (``serve/packed.py``)
-without a separate dispatch per matmul:
-
-* ``paged_decode_attn(..., wo_vals, wo_meta)`` — attn -> o_proj: at the
-  last table column the normalized per-head output hits the rebuilt
-  ``wo`` tile in VMEM and accumulates into the (1, d_model) output
-  block across kv heads (the block revisits over ``h``/``j``), so the
-  attention output never round-trips HBM before the projection.
-* ``fused_mlp24`` — the whole decode MLP (gate/up/down or fc1/fc2) in
-  ONE pallas_call, grid over d_ff tiles: every packed operand tile is
-  rebuilt in VMEM with the same iota-compare trick as ``spmm24`` and
-  the hidden activation never leaves VMEM.
+``fused_mlp24`` runs the whole decode MLP (gate/up/down or fc1/fc2) over
+the packed-2:4 store (``serve/packed.py``) in ONE pallas_call, grid over
+d_ff tiles: every packed operand tile is rebuilt in VMEM with the same
+iota-compare trick as ``spmm24`` and the hidden activation never leaves
+VMEM.
 
 The jnp oracles live in ``kernels/ref.py``; ``kernels/ops.py`` routes
 CPU (and kernel-unfriendly shapes) to them — the oracle math is
@@ -47,53 +42,27 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.spmm24 import rebuild24_t, rebuild_chunk
+
 NEG_INF = -1e30
 
 
-def _rebuild24(vals: jnp.ndarray, meta: jnp.ndarray) -> jnp.ndarray:
-    """Rebuild a dense (rows, 2*valcols) tile from packed 2:4 slabs in
-    VMEM — strided iota-compare selects, no gather (same trick as
-    ``spmm24._kernel``)."""
-    v0, v1 = vals[:, 0::2], vals[:, 1::2]
-    mi = meta.astype(jnp.int32)
-    i0, i1 = mi & 3, (mi >> 2) & 3
-    rows, half = vals.shape
-    w = jnp.zeros((rows, half * 2), vals.dtype)
-    for g in range(4):
-        wg = v0 * (i0 == g).astype(vals.dtype) + v1 * (i1 == g).astype(vals.dtype)
-        w = w.at[:, g::4].set(wg)
-    return w
-
-
 # ---------------------------------------------------------------------------
-# block-table flash decode attention (+ optional packed o_proj epilogue)
+# block-table flash decode attention
 # ---------------------------------------------------------------------------
-def _attn_kernel(tab_ref, pos_ref, act_ref, q_ref, k_ref, v_ref, *rest,
-                 scale: float, block_size: int, window: int, softcap: float,
-                 fuse_o: bool):
-    if fuse_o:
-        wov_ref, wom_ref, out_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        out_ref, m_ref, l_ref, acc_ref = rest
+def _attn_kernel(tab_ref, pos_ref, act_ref, q_ref, k_ref, v_ref, out_ref,
+                 m_ref, l_ref, acc_ref, *, scale: float, block_size: int,
+                 window: int, softcap: float):
     s = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    nkv, g = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    g, hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0].astype(jnp.float32)                    # (g, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)                 # (bs, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-    if softcap > 0:
-        sc = jnp.tanh(sc / softcap) * softcap
 
     # absolute token positions covered by table column j; the per-slot
     # length mask (tok <= pos), the sliding-window cut and the
@@ -105,33 +74,32 @@ def _attn_kernel(tab_ref, pos_ref, act_ref, q_ref, k_ref, v_ref, *rest,
     valid = (tok <= p) & (act_ref[s] > 0)
     if window > 0:
         valid &= tok > p - window
-    sc = jnp.where(valid, sc, NEG_INF)
 
-    m_prev = m_ref[...]                                    # (g, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    pr = jnp.exp(sc - m_new)                               # (g, bs)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(pr, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pr, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for h in range(nkv):
+        q = q_ref[0, h].astype(jnp.float32)                # (g, hd)
+        k = k_ref[0, :, h, :].astype(jnp.float32)          # (bs, hd)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        if softcap > 0:
+            sc = jnp.tanh(sc / softcap) * softcap
+        sc = jnp.where(valid, sc, NEG_INF)
+
+        m_prev = m_ref[h]                                  # (g, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        pr = jnp.exp(sc - m_new)                           # (g, bs)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            pr, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
     @pl.when(j == nj - 1)
     def _finish():
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)  # (g, hd)
-        if not fuse_o:
-            out_ref[0, 0] = o.astype(out_ref.dtype)
-        else:
-            # packed o_proj epilogue: o hits this kv head's rebuilt wo
-            # slab and accumulates into the slot's (1, d) output block,
-            # which stays resident in VMEM across the h revisits
-            w = _rebuild24(wov_ref[...], wom_ref[...])     # (d, g*hd)
-            contrib = jax.lax.dot_general(
-                o.reshape(1, g * hd), w, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # (1, d)
-            prev = jnp.where(h == 0, jnp.zeros_like(out_ref[...]),
-                             out_ref[...])
-            out_ref[...] = prev + contrib
+        for h in range(nkv):
+            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)  # (g, hd)
+            out_ref[0, h] = o.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "window",
@@ -140,84 +108,77 @@ def paged_decode_attn(q: jnp.ndarray, k_pool: jnp.ndarray,
                       v_pool: jnp.ndarray, tables: jnp.ndarray,
                       pos: jnp.ndarray, active: jnp.ndarray, *,
                       block_size: int, window: int = 0, softcap: float = 0.0,
-                      wo_vals: jnp.ndarray = None,
-                      wo_meta: jnp.ndarray = None,
                       interpret: bool = False) -> jnp.ndarray:
     """Block-table flash decode: q (S, nq, hd) against the flat pools
     (T, nkv, hd), T = num_blocks * block_size.
 
     ``tables`` (S, MB) int32 block tables, ``pos`` (S,) per-slot write
-    positions, ``active`` (S,) bool.  Without the epilogue returns the
-    attention output (S, nq, hd) in q.dtype; with ``wo_vals``/``wo_meta``
-    (a packed-2:4 o_proj in paper layout (d_model, nq*hd)) returns the
-    projected (S, d_model) in float32.
+    positions, ``active`` (S,) bool.  Returns the attention output
+    (S, nq, hd) in q.dtype.
     """
     S, nq, hd = q.shape
     T, nkv, _ = k_pool.shape
     MB = tables.shape[1]
     g = nq // nkv
-    fuse_o = wo_vals is not None
-    scale = 1.0 / np.sqrt(hd)
 
     q4 = q.reshape(S, nkv, g, hd)
     kb = k_pool.reshape(T // block_size, block_size, nkv, hd)
     vb = v_pool.reshape(T // block_size, block_size, nkv, hd)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda s, h, j, tab, p, a: (s, h, 0, 0)),
-        pl.BlockSpec((1, block_size, 1, hd),
-                     lambda s, h, j, tab, p, a: (tab[s, j], 0, h, 0)),
-        pl.BlockSpec((1, block_size, 1, hd),
-                     lambda s, h, j, tab, p, a: (tab[s, j], 0, h, 0)),
-    ]
-    operands = [q4, kb, vb]
-    if fuse_o:
-        d = wo_vals.shape[0]
-        if (g * hd) % 4 != 0:
-            raise ValueError(f"fused o_proj needs g*hd % 4 == 0, got {g * hd}")
-        in_specs += [
-            pl.BlockSpec((d, g * hd // 2), lambda s, h, j, tab, p, a: (0, h)),
-            pl.BlockSpec((d, g * hd // 4), lambda s, h, j, tab, p, a: (0, h)),
-        ]
-        operands += [wo_vals, wo_meta]
-        out_spec = pl.BlockSpec((1, d), lambda s, h, j, tab, p, a: (s, 0))
-        out_shape = jax.ShapeDtypeStruct((S, d), jnp.float32)
-    else:
-        out_spec = pl.BlockSpec((1, 1, g, hd),
-                                lambda s, h, j, tab, p, a: (s, h, 0, 0))
-        out_shape = jax.ShapeDtypeStruct((S, nkv, g, hd), q.dtype)
-
     kernel = functools.partial(
-        _attn_kernel, scale=scale, block_size=block_size,
-        window=int(window or 0), softcap=float(softcap), fuse_o=fuse_o)
+        _attn_kernel, scale=1.0 / np.sqrt(hd), block_size=block_size,
+        window=int(window or 0), softcap=float(softcap))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, nkv, MB),
-        in_specs=in_specs,
-        out_specs=out_spec,
+        grid=(S, MB),
+        in_specs=[
+            pl.BlockSpec((1, nkv, g, hd), lambda s, j, tab, p, a: (s, 0, 0, 0)),
+            pl.BlockSpec((1, block_size, nkv, hd),
+                         lambda s, j, tab, p, a: (tab[s, j], 0, 0, 0)),
+            pl.BlockSpec((1, block_size, nkv, hd),
+                         lambda s, j, tab, p, a: (tab[s, j], 0, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, nkv, g, hd),
+                               lambda s, j, tab, p, a: (s, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),    # running max
-            pltpu.VMEM((g, 1), jnp.float32),    # running denom
-            pltpu.VMEM((g, hd), jnp.float32),   # output accumulator
+            pltpu.VMEM((nkv, g, 1), jnp.float32),    # running max
+            pltpu.VMEM((nkv, g, 1), jnp.float32),    # running denom
+            pltpu.VMEM((nkv, g, hd), jnp.float32),   # output accumulator
         ])
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, nkv, g, hd), q.dtype),
         interpret=interpret,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32),
-      active.astype(jnp.int32), *operands)
-    return out if fuse_o else out.reshape(S, nq, hd)
+      active.astype(jnp.int32), q4, kb, vb)
+    return out.reshape(S, nq, hd)
 
 
 # ---------------------------------------------------------------------------
 # fused packed-2:4 decode MLP: one dispatch for gate/up/down (or fc1/fc2)
 # ---------------------------------------------------------------------------
+def _packed_matmul(x, vals_ref, meta_ref, w_ref):
+    """x (B, 2*half) float32 @ W^T for the packed (rows, half) tile in
+    ``vals_ref``/``meta_ref`` -> (B, rows) float32, rebuilt and
+    contracted one ``rebuild_chunk`` of rows at a time."""
+    rows = vals_ref.shape[0]
+    c = rebuild_chunk(rows)
+    outs = []
+    for r in range(0, rows, c):
+        wt = rebuild24_t(vals_ref[r:r + c, :], meta_ref[r:r + c, :], w_ref)
+        outs.append(jax.lax.dot_general(
+            x, wt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+
+
 def _mlp_kernel(x_ref, *rest, act: str, gated: bool):
     if gated:
         (w1v_ref, w1m_ref, b1_ref, upv_ref, upm_ref, w2v_ref, w2m_ref,
-         b2_ref, out_ref, acc_ref) = rest
+         b2_ref, out_ref, acc_ref, w1_ref, w2_ref) = rest
     else:
         (w1v_ref, w1m_ref, b1_ref, w2v_ref, w2m_ref, b2_ref, out_ref,
-         acc_ref) = rest
+         acc_ref, w1_ref, w2_ref) = rest
     f = pl.program_id(0)
     nf = pl.num_programs(0)
 
@@ -227,18 +188,12 @@ def _mlp_kernel(x_ref, *rest, act: str, gated: bool):
                                         ).astype(jnp.float32)
 
     x = x_ref[...].astype(jnp.float32)                     # (B, d)
-    w1 = _rebuild24(w1v_ref[...], w1m_ref[...]).astype(jnp.float32)  # (bf, d)
-    h = jax.lax.dot_general(x, w1, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)      # (B, bf)
+    h = _packed_matmul(x, w1v_ref, w1m_ref, w1_ref)        # (B, bf)
     h = h + b1_ref[...]
     h = jax.nn.gelu(h) if act in ("gelu", "geglu") else jax.nn.silu(h)
     if gated:
-        up = _rebuild24(upv_ref[...], upm_ref[...]).astype(jnp.float32)
-        h = h * jax.lax.dot_general(x, up, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-    w2 = _rebuild24(w2v_ref[...], w2m_ref[...]).astype(jnp.float32)  # (do, bf)
-    acc_ref[...] += jax.lax.dot_general(
-        h, w2, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        h = h * _packed_matmul(x, upv_ref, upm_ref, w1_ref)
+    acc_ref[...] += _packed_matmul(h, w2v_ref, w2m_ref, w2_ref)
 
     @pl.when(f == nf - 1)
     def _done():
@@ -257,7 +212,7 @@ def fused_mlp24(x: jnp.ndarray, w1_vals, w1_meta, b1, up_vals, up_meta,
     Grid over d_ff tiles of ``bf``; the hidden activation tile lives and
     dies in VMEM — HBM traffic is x + packed weights + out.  ``bf``
     defaults to 512 so the quarter-width w2 meta tile (d_out, bf/4)
-    stays 128-lane aligned (PAL003).
+    stays 128-lane aligned.
     """
     B, d = x.shape
     f = w1_vals.shape[0]
@@ -302,7 +257,11 @@ def fused_mlp24(x: jnp.ndarray, w1_vals, w1_meta, b1, up_vals, up_meta,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((B, d_out), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, d_out), x.dtype),
-        scratch_shapes=[pltpu.VMEM((B, d_out), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((B, d_out), jnp.float32),
+            pltpu.VMEM((d, rebuild_chunk(bf_)), jnp.float32),     # w1/up
+            pltpu.VMEM((bf_, rebuild_chunk(d_out)), jnp.float32),  # w2
+        ],
         interpret=interpret,
     )(*operands)
     return out

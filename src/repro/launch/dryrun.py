@@ -38,10 +38,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import api
 from repro.configs.base import ALL_ARCHS, SHAPES, ShapeSpec, shape_applicable
 from repro.distributed import sharding as rules
+from repro.launch import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models.registry import ModelDef
 from repro.train import optim
-from repro.utils import compat
 
 _DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                 "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8, "u16": 2,
@@ -267,7 +267,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         mesh = make_debug_mesh(jax.device_count(), multi_pod=multi_pod)
     n_dev = int(np.prod(list(mesh.shape.values())))
     t0 = time.perf_counter()
-    with mesh, compat.set_mesh(mesh):
+    with mesh, jax.sharding.set_mesh(mesh):
         fn, args = build_lowerable(model, shape, mesh)
         lowered = fn.lower(*args)
         t_lower = time.perf_counter() - t0
@@ -275,7 +275,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.perf_counter() - t0
         ma = compiled.memory_analysis()
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text(), n_dev)
 
     rec = {
@@ -334,7 +334,7 @@ def _reduced_cfg(cfg, n_layers: int):
 def _cell_costs(model: ModelDef, shape: ShapeSpec, mesh, n_dev: int) -> Dict[str, Any]:
     fn, args = build_lowerable(model, shape, mesh)
     compiled = fn.lower(*args).compile()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text(), n_dev)
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
@@ -401,7 +401,7 @@ def run_cell_extrapolated(arch: str, shape_name: str, multi_pod: bool,
 
     from repro.models.registry import model_def
     t0 = time.perf_counter()
-    with mesh, compat.set_mesh(mesh):
+    with mesh, jax.sharding.set_mesh(mesh):
         c1 = _cell_costs(model_def(_reduced_cfg(cfg, L1)), shape, mesh, n_dev)
         c2 = _cell_costs(model_def(_reduced_cfg(cfg, L2)), shape, mesh, n_dev)
     elapsed = time.perf_counter() - t0
@@ -465,6 +465,7 @@ def main() -> None:
     ap.add_argument("--flash", action="store_true",
                     help="use the Pallas flash-attention kernel (Perf it. 3)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]
     cells = []

@@ -34,6 +34,7 @@ from repro.checkpoint import store
 from repro.core import sequential as seq_lib
 from repro.data import CorpusConfig, MarkovCorpus
 from repro.eval import quality_report
+from repro.launch import enable_compile_cache
 from repro.utils import get_logger
 
 log = get_logger("launch.evaluate")
@@ -192,6 +193,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "eval batches over the mesh 'data' axis")
     ap.add_argument("--out", default=None, help="write the JSON report here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     try:
         report = evaluate_run(args.checkpoint, args.recipe,

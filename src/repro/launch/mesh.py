@@ -14,12 +14,19 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """Mesh with Auto axes: the model code shards through NamedSharding
+    and shard_map regions (GSPMD), which Explicit axes reject."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def factor_debug_mesh(devices: int, multi_pod: bool = False
@@ -57,4 +64,4 @@ def factor_debug_mesh(devices: int, multi_pod: bool = False
 def make_debug_mesh(devices: int, multi_pod: bool = False):
     """Scaled-down mesh with the same axis names (tests / CI)."""
     shape, axes = factor_debug_mesh(devices, multi_pod=multi_pod)
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)

@@ -3,8 +3,12 @@ solver, report perplexity before/after.
 
     python -m repro.launch.prune --arch opt125m-proxy --method fista \
         --sparsity 50% --workers 4 --ckpt-dir /tmp/prune_ckpts
-    python -m repro.launch.prune --method admm --sparsity 2:4
+    python -m repro.launch.prune --method admm --sparsity 2:4 --smoke
     python -m repro.launch.prune --recipe my_recipe.json
+
+``--arch`` runs at its published widths; ``--smoke`` swaps in the
+reduced config (CPU-friendly) and records that choice in the checkpoint,
+so ``launch/evaluate.py`` and ``launch/serve.py`` reload the same model.
 
 This is the end-to-end path of the paper: calibration data -> layer-wise
 pruning with intra-layer error correction -> pruned checkpoint ->
@@ -18,8 +22,10 @@ import argparse
 import dataclasses
 import json
 import sys
+from typing import List, Optional
 
 from repro import api, obs
+from repro.launch import enable_compile_cache
 from repro.checkpoint import store
 from repro.core.solvers import registered_solvers
 from repro.data import CorpusConfig, MarkovCorpus
@@ -80,10 +86,13 @@ def recipe_from_args(args: argparse.Namespace) -> api.PruneRecipe:
         mesh=mesh)
 
 
-def main() -> int:
+def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="opt125m-proxy",
                     choices=list(api.ARCH_CHOICES))
+    ap.add_argument("--smoke", action="store_true",
+                    help="smoke-size config for --arch (recorded in the "
+                         "checkpoint)")
     ap.add_argument("--method", default="fista",
                     choices=sorted(registered_solvers()))
     ap.add_argument("--sparsity", default="50%", help="'50%%' or '2:4'")
@@ -116,7 +125,8 @@ def main() -> int:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--out", default=None, help="write a JSON report here")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     try:
         recipe = recipe_from_args(args)
@@ -127,7 +137,7 @@ def main() -> int:
     except (ValueError, FileNotFoundError) as exc:
         print(f"error: {exc}", file=sys.stderr)
         return 2
-    model = recipe.load_model(smoke=True)
+    model = recipe.load_model(smoke=args.smoke)
     corpus = MarkovCorpus(CorpusConfig(vocab=model.cfg.vocab, seed=args.seed))
 
     log.info("training the dense model (%d steps)", args.train_steps)
@@ -144,7 +154,7 @@ def main() -> int:
         # dense_model + the scheduler's unit_* checkpoints, which
         # launch/evaluate.py can assemble into the pruned model
         save_run_models(ckpt_dir, recipe, tr.params,
-                        corpus_seed=args.seed, smoke=True,
+                        corpus_seed=args.seed, smoke=args.smoke,
                         dense_ppl=dense_ppl)
 
     if executor is not None:
@@ -158,7 +168,7 @@ def main() -> int:
     if ckpt_dir:
         save_run_models(ckpt_dir, recipe, tr.params, pruned, reports,
                         save_dense=False,   # identical snapshot saved above
-                        corpus_seed=args.seed, smoke=True,
+                        corpus_seed=args.seed, smoke=args.smoke,
                         dense_ppl=dense_ppl, pruned_ppl=pruned_ppl)
         log.info("saved %s + %s under %s", DENSE_MODEL, PRUNED_MODEL, ckpt_dir)
         obs_dir = obs.save_run_dir(ckpt_dir)

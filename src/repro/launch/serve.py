@@ -28,6 +28,7 @@ import numpy as np
 
 from repro import api, obs
 from repro.checkpoint import store
+from repro.launch import enable_compile_cache
 from repro.serve import (BatchConfig, ContinuousBatcher, PoolExhausted,
                          synthetic_trace)
 from repro.utils import get_logger
@@ -123,6 +124,10 @@ def serve_trace(model, params, args: argparse.Namespace) -> dict:
         "resumes": batcher.stats["resumes"],
         "prefix_hit_tokens": hit_tokens,
         "prefix_hit_rate": hit_tokens / max(prompt_tokens, 1),
+        # what each request generated, by request id: the output a
+        # caller checks (dense against packed, one mesh against another)
+        "request_tokens": {str(r.id): [int(t) for t in r.tokens]
+                           for r in results},
         "config": {"slots": cfg.slots, "block_size": cfg.block_size,
                    "num_blocks": cfg.num_blocks,
                    "context_len": cfg.context_len, "rate": args.rate,
@@ -199,6 +204,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="write a Chrome/Perfetto trace.json of the run's "
                          "spans here (implies recording, like --metrics-out)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.metrics_out or args.trace_out:
         # must precede the batcher build: its instruments bind in __init__

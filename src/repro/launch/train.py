@@ -13,6 +13,7 @@ import jax
 
 from repro.configs.base import ALL_ARCHS
 from repro.data import CorpusConfig, MarkovCorpus
+from repro.launch import enable_compile_cache
 from repro.models.registry import load_arch
 from repro.train import AdamWConfig, TrainConfig, Trainer, evaluate_ppl
 from repro.utils import get_logger
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     model = load_arch(args.arch, smoke=args.smoke)
     corpus = MarkovCorpus(CorpusConfig(vocab=model.cfg.vocab, seed=args.seed))

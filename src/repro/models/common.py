@@ -192,12 +192,10 @@ def _flash_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ambient mesh (single-device tests) this is a plain local call.
     """
     from repro.kernels import ops as kops
-    from repro.utils import compat
 
-    mesh = compat.ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return kops.flash_mha(q, k, v, causal, window)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, Hq, S, D = q.shape
@@ -230,13 +228,13 @@ def _flash_sharded(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             v_ = jax.lax.dynamic_slice_in_dim(v_, kv_head, 1, axis=1)
         return kops.flash_mha(q_, k_, v_, causal, window)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, hq_spec, None, None),
                   P(bspec, hkv_spec, None, None),
                   P(bspec, hkv_spec, None, None)),
         out_specs=P(bspec, hq_spec, None, None),
-        check_rep=False)  # pallas out_shape carries no vma/rep annotations
+        check_vma=False)  # pallas out_shape carries no vma annotations
     return fn(q, k, v)
 
 
@@ -354,45 +352,38 @@ def mha_decode(cfg: ModelConfig, p: Params, x: jnp.ndarray, pos: jnp.ndarray,
 def _paged_attn_sharded(q: jnp.ndarray, k_pool: jnp.ndarray,
                         v_pool: jnp.ndarray, tables: jnp.ndarray,
                         pos: jnp.ndarray, active: jnp.ndarray,
-                        block_size: int, window: int, softcap: float,
-                        wo: Optional[Params] = None) -> jnp.ndarray:
+                        block_size: int, window: int, softcap: float
+                        ) -> jnp.ndarray:
     """Block-table decode attention behind an optional shard_map boundary.
 
     Mirrors :func:`_flash_sharded`: under an ambient mesh the kv-head
     axis of the pools (and the group-aligned q heads) maps onto "model",
-    so each device runs the kernel grid over its local heads — the head
-    axis IS a grid axis, so sharding it just shrinks the grid.  The
-    scalar-prefetch operands (tables/pos/active) replicate.  The packed
-    o_proj epilogue only fuses unsharded: under TP the projection stays
-    a separate dense() so GSPMD can psum head-partial contributions.
-    Without an ambient mesh this is a plain local dispatch.
+    so each device runs the kernel over its local heads.  The
+    scalar-prefetch operands (tables/pos/active) replicate.  Without an
+    ambient mesh this is a plain local dispatch.
     """
     from repro.kernels import ops as kops
-    from repro.utils import compat
 
     def local(q_, k_, v_, tab_, pos_, act_):
         return kops.paged_decode_attn(
             q_, k_, v_, tab_, pos_, act_, block_size=block_size,
-            window=window, softcap=softcap,
-            wo_vals=None if wo is None else wo["vals"],
-            wo_meta=None if wo is None else wo["meta"])
+            window=window, softcap=softcap)
 
-    mesh = compat.ambient_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     nkv = k_pool.shape[1]
-    if (mesh is None or "model" not in mesh.axis_names or wo is not None
+    if ("model" not in mesh.axis_names
             or nkv % mesh.shape["model"] != 0):
         return local(q, k_pool, v_pool, tables, pos, active)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     # q heads shard group-aligned with kv heads: nkv % msize == 0 makes
     # every "model" shard's contiguous q chunk a whole set of kv groups
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, "model", None), P(None, "model", None),
                   P(None, "model", None), P(None, None), P(None), P(None)),
         out_specs=P(None, "model", None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_pool, v_pool, tables, pos, active)
 
 
@@ -425,8 +416,7 @@ def mha_decode_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     ``impl="fused"`` (with ``tables``/``block_size`` in place of
     ``gather_idx``) routes the attention through the block-table flash
     kernel (kernels/paged_attention.py): the kernel walks the table via
-    scalar prefetch instead of materializing the (S, W, nkv, hd) gather,
-    and when ``wo`` is packed the o_proj fuses into the kernel epilogue.
+    scalar prefetch instead of materializing the (S, W, nkv, hd) gather.
     On CPU / kernel-unfriendly shapes the fused route falls back to an
     oracle that repeats this function's exact math, so the two impls
     stay token-identical (DESIGN.md §11).
@@ -447,16 +437,9 @@ def mha_decode_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     v = cache["v"].at[write_idx].set(v_new[:, 0].astype(cache["v"].dtype))
     new_cache = {"k": k, "v": v}
     if impl == "fused" and tables is not None:
-        from repro.kernels import ops as kops
-        wo = p["wo"]
-        fuse_o = (isinstance(wo, dict) and "vals" in wo
-                  and kops.use_decode_kernel(hd, block_size))
         o = _paged_attn_sharded(q[:, 0], k, v, tables, pos, active,
                                 block_size, int(window or 0),
-                                float(cfg.attn_logit_softcap),
-                                wo if fuse_o else None)
-        if fuse_o:
-            return o.astype(x.dtype)[:, None, :], new_cache
+                                float(cfg.attn_logit_softcap))
         out = o.reshape(o.shape[0], 1, nq * hd)
         return dense(out, p["wo"]), new_cache
     kg = jnp.take(k, gather_idx, axis=0)                          # (S,W,nkv,hd)

@@ -300,8 +300,8 @@ def paged_serve_step(cfg: ModelConfig, params: Params,
 
     ``impl="fused"`` skips materializing the (S, W) position-order
     ``gather_idx`` and hands the block tables straight to the fused
-    decode fast path (block-table flash attention + packed-operand
-    epilogues, kernels/paged_attention.py); ``"reference"`` is the
+    decode fast path (block-table flash attention + the packed MLP,
+    kernels/paged_attention.py); ``"reference"`` is the
     gather path that anchors it bitwise.
     """
     S, MB = tables.shape

@@ -178,8 +178,9 @@ class ContinuousBatcher:
         self.params, self.sparse_stats = prepare_serving_params(params, cfg.sparse)
         # accounting tree (self.params, may stay packed — serve_bench
         # meters its bytes) vs compute tree (packed.decode_view: identity
-        # on TPU, cached dense unpack on CPU)
-        exec_params = packed_lib.decode_view(self.params)
+        # on one TPU, cached dense unpack on CPU or under a mesh)
+        exec_params = packed_lib.decode_view(self.params,
+                                             sharded=executor is not None)
         self.pool = kv_cache.BlockPool(cfg.num_blocks, cfg.block_size)
         self.pool_state = model.init_paged_state(cfg.num_blocks, cfg.block_size)
         if executor is not None:

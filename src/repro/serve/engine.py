@@ -28,7 +28,7 @@ dense view on CPU, where per-step unpacking made packed serving slower
 than dense (see serve/packed.py).
 
 ``ServeConfig.decode_impl`` selects the decode fast path ("fused", the
-default: block-table flash attention + fused packed epilogues in the
+default: block-table flash attention + the fused packed MLP in the
 *paged* step) vs the reference gather path that anchors it bitwise.
 The contiguous-cache engine here has no paged step, so it serves via
 the reference path either way — the flag is validated and forwarded for
@@ -137,8 +137,10 @@ class Engine:
             log.debug("decode_impl='fused' on a family without a paged "
                       "step: serving via the reference decode path")
         # accounting tree (self.params, may stay packed) vs compute tree
-        # (the decode view: identity on TPU, cached dense unpack on CPU)
-        exec_params = packed_lib.decode_view(self.params)
+        # (the decode view: identity on one TPU, cached dense unpack on
+        # CPU or under a mesh)
+        exec_params = packed_lib.decode_view(self.params,
+                                             sharded=executor is not None)
         if executor is not None:
             same = exec_params is self.params
             self.params = executor.shard_params(self.params)

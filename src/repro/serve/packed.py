@@ -100,11 +100,16 @@ def count_packed(params: Any) -> int:
     return rec(params)
 
 
-def decode_view(params: Any) -> Any:
+def decode_view(params: Any, sharded: bool = False) -> Any:
     """The representation the decode step should *compute* with.
 
-    On TPU: identity — packed leaves feed the spmm24 / fused-epilogue
+    On TPU: identity — packed leaves feed the spmm24 / fused-MLP
     kernels, which is the whole point of packing (0.625x weight traffic).
+    ``sharded=True`` (a step partitioned over a mesh) takes the dense
+    view on every backend: JAX cannot partition a Mosaic kernel outside
+    ``shard_map``, and the sharding rules replicate packed stores, so each
+    chip would read 0.625x of every weight where the dense view sharded
+    over "model" reads 1/model of it.
 
     On CPU there is no packed-matmul hardware to win on, and unpacking
     inside the jitted per-token step (or interpreting the Pallas kernel)
@@ -117,15 +122,16 @@ def decode_view(params: Any) -> Any:
     matmuls.  Identity when nothing is packed.
     """
     import jax
-    if jax.default_backend() == "tpu":
+    if jax.default_backend() == "tpu" and not sharded:
         return params
     n = count_packed(params)
     if n == 0:
         return params
     from repro.utils import get_logger
     get_logger("serve").info(
-        "CPU backend: caching dense decode view of %d packed operators "
-        "(packed tree kept for accounting)", n)
+        "%s: caching dense decode view of %d packed operators "
+        "(packed tree kept for accounting)",
+        "sharded step" if sharded else "CPU backend", n)
     return unpack_tree(params)
 
 

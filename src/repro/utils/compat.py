@@ -1,65 +1,10 @@
-"""Shims over JAX API differences between the versions we support.
-
-The mesh-context API moved around 0.5.x: ``jax.sharding.get_abstract_mesh``
-/ ``set_mesh`` exist on new JAX, while 0.4.x exposes the abstract mesh only
-under ``jax._src.mesh`` and tracks the physical mesh via
-``thread_resources``.  Model code calls these helpers instead of either API
-directly.
-"""
+"""Process-environment helpers shared by the code that spawns JAX
+subprocesses (distributed test cases, the mesh-gram bench children,
+CLI tests)."""
 from __future__ import annotations
 
-import contextlib
+import os
 from typing import Optional
-
-import jax
-
-
-def ambient_mesh() -> Optional[object]:
-    """The ambient (abstract or physical) device mesh, or None.
-
-    Returns something with ``.axis_names`` and a dict-like ``.shape``
-    (both ``jax.sharding.Mesh`` and ``AbstractMesh`` qualify), usable as
-    the ``mesh=`` argument of ``shard_map``.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        try:
-            from jax._src import mesh as _mesh_lib
-            get = getattr(_mesh_lib, "get_abstract_mesh", None)
-        except ImportError:
-            get = None
-    if get is not None:
-        try:
-            mesh = get()
-            if mesh is not None and getattr(mesh, "axis_names", ()):
-                return mesh
-        except Exception:  # noqa: BLE001 — fall through to the physical mesh
-            pass
-    try:
-        from jax.interpreters import pxla
-        phys = pxla.thread_resources.env.physical_mesh
-        if phys is not None and not phys.empty:
-            return phys
-    except Exception:  # noqa: BLE001
-        pass
-    return None
-
-
-def pvary(x, axes):
-    """``jax.lax.pvary`` when it exists (the vma type system of newer JAX);
-    identity on 0.4.x, which has no varying-manifest annotations."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axes) if fn is not None else x
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version:
-    0.4.x returns a one-element list of per-device dicts, newer JAX the
-    dict itself (and None is possible on exotic backends)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
 
 
 def force_host_devices_flags(devices: int, base: Optional[str] = None) -> str:
@@ -71,20 +16,8 @@ def force_host_devices_flags(devices: int, base: Optional[str] = None) -> str:
     spawner that fakes a device count (distributed test cases, the
     mesh-gram bench children, CLI tests) must route through this.
     """
-    import os
-
     kept = [f for f in (os.environ.get("XLA_FLAGS", "") if base is None
                         else base).split()
             if not f.startswith("--xla_force_host_platform_device_count")]
     return " ".join(
         [f"--xla_force_host_platform_device_count={devices}"] + kept)
-
-
-def set_mesh(mesh):
-    """``jax.sharding.set_mesh(mesh)`` when available, else a no-op context
-    (on 0.4.x the enclosing ``with mesh:`` already installs the physical
-    mesh that :func:`ambient_mesh` falls back to)."""
-    setter = getattr(jax.sharding, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return contextlib.nullcontext()

@@ -1,9 +1,9 @@
-"""MESH001 true-positive: shard_map without explicit check_rep (parsed
+"""MESH001 true-positive: shard_map without explicit check_vma (parsed
 only, never imported)."""
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import PartitionSpec as P
 
 
 def build(mesh, local):
-    return shard_map(local, mesh=mesh, in_specs=(P("x"),),
-                     out_specs=P("x"))       # MESH001: implicit check_rep
+    return jax.shard_map(local, mesh=mesh, in_specs=(P("x"),),
+                         out_specs=P("x"))   # MESH001: implicit check_vma

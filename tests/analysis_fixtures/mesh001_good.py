@@ -1,8 +1,8 @@
 """MESH001 true-negative: the replication contract is explicit."""
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import PartitionSpec as P
 
 
 def build(mesh, local):
-    return shard_map(local, mesh=mesh, in_specs=(P("x"),),
-                     out_specs=P("x"), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P("x"),),
+                         out_specs=P("x"), check_vma=False)

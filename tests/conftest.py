@@ -1,13 +1,10 @@
 """Shared test-session hygiene.
 
 The tier-1 suite compiles a few thousand distinct XLA programs in one
-process.  On single-core CPU runners, jaxlib 0.4.37's compiler
-eventually segfaults partway through the later modules (observed
-repeatedly around test ~315/369, in a *different* test each run, with
-RSS under 6 GB — accumulated compiler/executable state, not memory
-pressure).  Dropping the jit caches at module boundaries keeps the
-live-executable population bounded; each module recompiles what it
-actually uses, which costs a little wall time and changes no results.
+process.  Dropping the jit caches at module boundaries keeps the
+population of live executables (and the compiler state behind them)
+bounded; each module recompiles what it actually uses, which costs a
+little wall time and changes no results.
 """
 import jax
 import pytest

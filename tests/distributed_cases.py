@@ -30,6 +30,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
+from repro.launch.mesh import make_mesh  # noqa: E402
+
 if jax.device_count() < _DEVICES:
     # the backend ignored the fake-device flag (e.g. a GPU platform):
     # only 1 device is visible — skip cleanly instead of failing
@@ -52,7 +54,7 @@ def case_rowfista():
     from repro.core import gram as gram_lib
     from repro.distributed.rowfista import sharded_solve
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     m, n = 32, 48
     a = rng.normal(size=(n, n)).astype(np.float32) * 0.3
@@ -70,7 +72,7 @@ def case_gram_psum():
     from repro.core import gram as gram_lib
     from repro.distributed.rowfista import sharded_accumulate
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(1)
     p, n, m = 64, 16, 8
     xd = rng.normal(size=(p, n)).astype(np.float32)
@@ -115,7 +117,7 @@ def case_sharded_train():
 
     p_ref, o_ref, l_ref = jax.jit(ref_step)(params, opt, batch)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     build = make_train_step(model, mesh, ocfg, donate=False)
     fn, _ = build(params, opt, batch)
     p_sh, o_sh, metrics = fn(params, opt, batch)
@@ -133,7 +135,7 @@ def case_pipeline():
     from repro.distributed.pipeline import (pipeline_apply, split_microbatches,
                                             merge_microbatches, stack_to_stages)
 
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    mesh = make_mesh((4, 2), ("pod", "data"))
     rng = np.random.default_rng(2)
     L, D = 8, 16
     ws = jnp.asarray(rng.normal(size=(L, D, D)).astype(np.float32) * 0.2)
@@ -164,7 +166,7 @@ def case_compression():
     from repro.distributed.compression import (compressed_allreduce,
                                                ef_compress, init_residuals)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(3)
     D = 8
     grads = {"w": jnp.asarray(rng.normal(size=(D, 16, 8)).astype(np.float32))}
@@ -211,7 +213,7 @@ def case_moe_sharded():
     params = model.init(jax.random.PRNGKey(0))
     opt = optim.init(params)
     batch = model.make_batch(jax.random.PRNGKey(1), 4, 16)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     build = make_train_step(model, mesh, optim.AdamWConfig(), donate=False)
     fn, _ = build(params, opt, batch)
     _, _, metrics = fn(params, opt, batch)
@@ -438,12 +440,8 @@ def case_paged_attn_shardmap():
     """The fused decode attention's shard_map boundary (models/common.
     _paged_attn_sharded): with the KV pools heads-sharded over "model"
     and the block table / positions replicated, the output equals the
-    meshless local dispatch — and a packed o_proj forces the unsharded
-    bypass (the projection must stay a dense() so GSPMD can psum the
-    head-partials), same result either way."""
-    from repro.kernels import ops as kops
+    meshless local dispatch."""
     from repro.models import common
-    from repro.utils import compat
 
     rng = np.random.default_rng(0)
     S, nkv, g, hd, NB, BS = 3, 4, 2, 8, 10, 4   # nkv % model_parallel == 0
@@ -464,21 +462,12 @@ def case_paged_attn_shardmap():
     active = jnp.asarray([True, True, False])
     args = (q, k, v, tables, pos, active, BS, 3, 0.0)
 
-    wo_dense = rng.standard_normal((16, nkv * g * hd)).astype(np.float32)
-    keep = rng.random((16, nkv * g * hd // 4, 4)).argsort(axis=-1) < 2
-    wv, wm = kops.pack24(jnp.asarray(wo_dense * keep.reshape(wo_dense.shape)))
-    wo = {"vals": wv, "meta": wm}
-
     want = common._paged_attn_sharded(*args)
-    want_proj = common._paged_attn_sharded(*args, wo=wo)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
-    with mesh, compat.set_mesh(mesh):
+    mesh = make_mesh((2, 4), ("data", "model"))
+    with mesh, jax.sharding.set_mesh(mesh):
         got = common._paged_attn_sharded(*args)
-        got_proj = common._paged_attn_sharded(*args, wo=wo)
     act = np.asarray(active)
     np.testing.assert_array_equal(np.asarray(got)[act], np.asarray(want)[act])
-    np.testing.assert_array_equal(np.asarray(got_proj)[act],
-                                  np.asarray(want_proj)[act])
 
 
 def case_engine_tp_parity():

@@ -128,11 +128,11 @@ class TestOBS001RecordingPlacement:
 # ---------------------------------------------------------------------------
 class TestMESH001CheckRep:
     def test_implicit_check_rep_flagged(self):
-        found = rules_mesh.check_shard_map_check_rep(parse("mesh001_bad.py"))
+        found = rules_mesh.check_shard_map_check_vma(parse("mesh001_bad.py"))
         assert rules_of(found) == ["MESH001"]
 
     def test_explicit_check_rep_clean(self):
-        assert rules_mesh.check_shard_map_check_rep(
+        assert rules_mesh.check_shard_map_check_vma(
             parse("mesh001_good.py")) == []
 
 

@@ -116,12 +116,13 @@ def test_window_mask_helper_shared_by_decode_paths():
         cfg, p, x, jnp.asarray(pos), flat,
         jnp.asarray(np.arange(S) * W + pos), gather, jnp.ones((S,), bool),
         cfg.window)
+    # all S rows decode at slot b's position and row b is compared: XLA's
+    # CPU dot rounds a 1-row matmul differently from an S-row one
     for b in range(S):
-        solo, _ = common.mha_decode(cfg, p, x[b:b + 1], jnp.int32(pos[b]),
-                                    {"k": ck[b:b + 1], "v": cv[b:b + 1]},
-                                    window=cfg.window)
+        contig, _ = common.mha_decode(cfg, p, x, jnp.int32(pos[b]),
+                                      {"k": ck, "v": cv}, window=cfg.window)
         np.testing.assert_array_equal(np.asarray(paged[b:b + 1]),
-                                      np.asarray(solo))
+                                      np.asarray(contig[b:b + 1]))
 
 
 def test_flash_attention_matches_xla_forward():

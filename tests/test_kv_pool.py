@@ -142,16 +142,17 @@ class TestPagedReadBitwise:
             jnp.asarray(write_idx), jnp.asarray(gather),
             jnp.ones((S,), bool))
 
+        # all S rows decode at slot b's position and row b is compared:
+        # XLA's CPU dot rounds a 1-row matmul differently from an S-row one
         for b in range(S):
             out_solo, new_solo = common.mha_decode(
-                cfg, p, x[b:b + 1], jnp.int32(pos[b]),
-                {"k": ck[b:b + 1], "v": cv[b:b + 1]})
+                cfg, p, x, jnp.int32(pos[b]), {"k": ck, "v": cv})
             np.testing.assert_array_equal(np.asarray(out_paged[b:b + 1]),
-                                          np.asarray(out_solo))
+                                          np.asarray(out_solo[b:b + 1]))
             # the written K/V row matches too (cache side of the contract)
             np.testing.assert_array_equal(
                 np.asarray(new_paged["k"][gather[b]])[pos[b]],
-                np.asarray(new_solo["k"])[0, pos[b]])
+                np.asarray(new_solo["k"])[b, pos[b]])
 
 
 class TestDefragDeviceMove:

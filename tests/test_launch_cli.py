@@ -18,6 +18,34 @@ def _run(module, *args, timeout=900, env_extra=None):
                           cwd=ROOT)
 
 
+def test_compile_cache_placement(tmp_path):
+    """The launchers' compile cache: JAX_COMPILATION_CACHE_DIR when set (and
+    entries land there), else the fixed ``.jax_cache/`` at the checkout root."""
+    probe = ("import jax; from repro.launch import enable_compile_cache; "
+             "print(enable_compile_cache()); "
+             "print(jax.config.jax_compilation_cache_dir); "
+             "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0); "
+             "jax.jit(lambda x: x * 3 + 1)(2.0).block_until_ready()")
+    cache = tmp_path / "cache"
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(cache))
+    out = subprocess.run([sys.executable, "-c", probe], env=env, cwd=tmp_path,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [str(cache), str(cache)]
+    assert any(cache.iterdir())
+
+    env.pop("JAX_COMPILATION_CACHE_DIR")
+    probe = ("import jax; from repro.launch import enable_compile_cache; "
+             "print(enable_compile_cache()); "
+             "print(jax.config.jax_compilation_cache_dir)")
+    out = subprocess.run([sys.executable, "-c", probe], env=env, cwd=tmp_path,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    want = os.path.join(ROOT, ".jax_cache")
+    assert out.stdout.split() == [want, want]
+
+
 def test_train_cli_smoke():
     out = _run("repro.launch.train", "--arch", "opt125m-proxy",
                "--steps", "20", "--batch", "4", "--seq", "32")
@@ -37,7 +65,7 @@ def test_train_cli_resume(tmp_path):
 
 def test_prune_cli_end_to_end(tmp_path):
     report = tmp_path / "report.json"
-    out = _run("repro.launch.prune", "--arch", "opt125m-proxy",
+    out = _run("repro.launch.prune", "--arch", "opt125m-proxy", "--smoke",
                "--method", "fista", "--sparsity", "2:4",
                "--train-steps", "40", "--calib-sequences", "8",
                "--calib-seq-len", "32", "--workers", "2",
@@ -53,7 +81,7 @@ def test_prune_then_evaluate_cli(tmp_path):
     """The quality loop of the README: prune --ckpt-dir, then evaluate the
     run's pruned checkpoint against its dense reference."""
     run_dir = tmp_path / "run"
-    out = _run("repro.launch.prune", "--arch", "opt125m-proxy",
+    out = _run("repro.launch.prune", "--arch", "opt125m-proxy", "--smoke",
                "--method", "fista", "--sparsity", "2:4",
                "--train-steps", "30", "--calib-sequences", "8",
                "--calib-seq-len", "32", "--workers", "2",
@@ -156,7 +184,7 @@ def test_serve_cli_rejects_bad_mesh():
 def test_prune_cli_rejects_bad_mesh():
     """A bad --mesh must die with a clean error/exit 2 BEFORE any
     training happens — same contract as the evaluate/serve CLIs."""
-    out = _run("repro.launch.prune", "--arch", "opt125m-proxy",
+    out = _run("repro.launch.prune", "--arch", "opt125m-proxy", "--smoke",
                "--train-steps", "9999", "--mesh", "4y2", timeout=120)
     assert out.returncode == 2, out.stdout + out.stderr
     assert "mesh" in out.stderr.lower()
@@ -173,7 +201,7 @@ def test_evaluate_cli_mesh_unavailable_degrades(tmp_path):
     # prune under 8 fake host devices with --mesh 8x1 so the stored
     # recipe actually records the mesh this machine won't have
     fake8 = {"XLA_FLAGS": force_host_devices_flags(8)}
-    out = _run("repro.launch.prune", "--arch", "opt125m-proxy",
+    out = _run("repro.launch.prune", "--arch", "opt125m-proxy", "--smoke",
                "--method", "wanda", "--sparsity", "2:4",
                "--train-steps", "6", "--calib-sequences", "8",
                "--calib-seq-len", "32", "--workers", "1", "--mesh", "8x1",

@@ -218,12 +218,15 @@ class TestPagedBitwise:
             {"k": state["k"][0], "v": state["v"][0]},
             jnp.asarray(gather[np.arange(S), pos]), jnp.asarray(gather),
             jnp.ones((S,), bool))
+        # the contiguous reference decodes all S rows at slot b's position
+        # and row b is compared: XLA's CPU dot rounds a 1-row matmul
+        # differently from an S-row one, so a batch-1 reference would pin
+        # the matmul's row count, not the paged read
         for b in range(S):
-            out_solo, _ = common.mha_decode(
-                cfg, p, x[b:b + 1], jnp.int32(pos[b]),
-                {"k": ck[b:b + 1], "v": cv[b:b + 1]})
+            out_contig, _ = common.mha_decode(
+                cfg, p, x, jnp.int32(pos[b]), {"k": ck, "v": cv})
             np.testing.assert_array_equal(np.asarray(out_paged[b:b + 1]),
-                                          np.asarray(out_solo))
+                                          np.asarray(out_contig[b:b + 1]))
 
 
 class TestDecodeImpl:

@@ -238,3 +238,25 @@ class TestServe:
         dense_gen = Engine(model, sparse, ServeConfig(max_new_tokens=4)).generate(prompt)
         packed_gen = Engine(model, packed, ServeConfig(max_new_tokens=4)).generate(prompt)
         np.testing.assert_array_equal(dense_gen, packed_gen)
+
+    def test_decode_view_dense_when_sharded(self, tiny_setup, monkeypatch):
+        """One TPU computes with the packed store itself; a step sharded
+        over a mesh computes with the dense view (a Mosaic kernel cannot
+        sit in a partitioned program, and packed stores replicate)."""
+        from repro.core.sparsity import round_nm
+        from repro.serve import packed as packed_lib
+        from repro.utils.tree import tree_map_with_path
+        model, _ = tiny_setup
+        params = model.init(jax.random.PRNGKey(2))
+        def prune(path, w):
+            if w.ndim == 2 and "embed" not in path and w.shape[0] % 4 == 0:
+                return round_nm(w.T.astype(jnp.float32), 2, 4).T.astype(w.dtype)
+            return w
+        packed, _ = pack_tree(tree_map_with_path(prune, params), dtype=None)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert packed_lib.decode_view(packed) is packed
+        view = packed_lib.decode_view(packed, sharded=True)
+        assert packed_lib.count_packed(view) == 0
+        for a, b in zip(jax.tree_util.tree_leaves(view),
+                        jax.tree_util.tree_leaves(unpack_tree(packed))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
