@@ -463,10 +463,13 @@ def bench_obs_overhead(model, params) -> Dict:
                 first[mode] = res
             meds[mode].append(_median_step(b))
     # time the real per-tick recording path on the last instrumented
-    # batcher (its instruments and pool state are live)
+    # batcher (its instruments and pool state are live), with the gap of
+    # every slot buffered as a decode tick buffers them
     reps = 2000
+    gaps = [1e-3] * BATCH.slots
     t0 = time.perf_counter()
     for _ in range(reps):
+        b._pend_itl.extend(gaps)
         b._record_tick_obs(BATCH.slots)
     rec_s = (time.perf_counter() - t0) / reps
     from repro.obs import spans as spans_lib

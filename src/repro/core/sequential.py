@@ -352,6 +352,10 @@ def prune_unit(model: ModelDef, spec: UnitSpec, dense_unit: Any,
                 else:
                     stats = _group_stats_scan(stats, current, ws, caps_stacked,
                                               pstacked, **static_kw)
+            if obs.enabled():
+                # the scan is dispatched asynchronously: while recording,
+                # the span and the histogram end at its completion
+                jax.block_until_ready(stats)
         if obs.enabled():
             obs.registry().histogram(
                 "prune.gram_scan_s", obs.LATENCY_BUCKETS_S).observe(

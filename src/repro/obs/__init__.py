@@ -4,12 +4,20 @@ One process-global :class:`~repro.obs.spans.SpanRecorder` and one
 :class:`~repro.obs.metrics.MetricsRegistry`, toggled by
 :func:`enable`/:func:`disable`.  Instrumentation sites follow two rules:
 
-* **spans** go through :func:`span` — it returns a shared no-op context
-  manager while disabled, so span sites cost one function call;
+* **spans** go through :func:`span` (or :func:`step_span` for one
+  iteration of a loop) — it returns a shared no-op context manager
+  while disabled and no profiler trace runs, so span sites cost one
+  function call and one check;
 * **metrics** in hot loops fetch their instruments ONCE at construction
   behind an ``enabled()`` check (see ``serve/batcher.py``) so the
   per-tick cost is a guarded attribute access + a bisect, never a
   registry lookup; the registry itself is reached via :func:`registry`.
+
+Spans reach the profiler too: while a ``jax.profiler`` trace runs,
+each span enters a ``jax.profiler.TraceAnnotation`` of its name, with
+its attributes, whether or not recording is on.  So every span of the
+program (``prune.*``, ``mesh.*``, ``serve.*``) lies in the trace's
+``/host:`` plane beside the device's events, with no :func:`enable`.
 
 Recording never touches device values before they are already on the
 host: solver convergence traces come out of the fused while_loops as
@@ -30,9 +38,10 @@ from repro.obs import metrics as metrics_lib
 from repro.obs import spans as spans_lib
 from repro.obs.metrics import (COUNT_BUCKETS, FRACTION_BUCKETS,
                                LATENCY_BUCKETS_S, MetricsRegistry)
-from repro.obs.spans import NULL_SPAN, Span, SpanRecorder
+from repro.obs.spans import NULL_SPAN, Span, SpanRecorder, TraceAnnotation
 
-__all__ = ["enable", "disable", "enabled", "span", "registry", "recorder",
+__all__ = ["enable", "disable", "enabled", "span", "step_span", "registry",
+           "recorder",
            "save_run_dir", "MetricsRegistry", "SpanRecorder", "Span",
            "LATENCY_BUCKETS_S", "COUNT_BUCKETS", "FRACTION_BUCKETS",
            "OBS_SUBDIR"]
@@ -66,10 +75,23 @@ def enabled() -> bool:
 
 
 def span(name: str, **attrs):
-    """A context manager timing ``name``; no-op while disabled."""
-    if not _enabled:
-        return NULL_SPAN
-    return _recorder.span(name, **attrs)
+    """A context manager timing ``name``: a ring span while recording,
+    and a profiler annotation while a trace runs; else a no-op."""
+    if _enabled:
+        return _recorder.span(name, **attrs)
+    if TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **attrs)
+    return NULL_SPAN
+
+
+def step_span(name: str, step: int, **attrs):
+    """:func:`span` for iteration ``step`` of a loop: in a profiler trace
+    a ``StepTraceAnnotation``, in the ring a plain span."""
+    if _enabled:
+        return _recorder.step_span(name, step, **attrs)
+    if TraceAnnotation.is_enabled():
+        return spans_lib.annotation(name, attrs, step)
+    return NULL_SPAN
 
 
 # named `registry` (not `metrics`) so the accessor never shadows the

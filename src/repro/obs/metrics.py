@@ -128,6 +128,12 @@ class Histogram:
         if v > self.vmax:
             self.vmax = v
 
+    def observe_many(self, values: Sequence[float]) -> None:
+        """:meth:`observe` each of ``values``: one recording call for a
+        batch buffered on the host (a decode tick's per-slot gaps)."""
+        for v in values:
+            self.observe(v)
+
     @property
     def mean(self) -> Optional[float]:
         return self.sum / self.total if self.total else None

@@ -12,13 +12,22 @@ memory cost.  Nesting is tracked per thread — the scheduler's worker
 threads each get their own stack, and their spans land on separate
 Perfetto tracks via ``tid``.
 
+While a ``jax.profiler`` trace runs, every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name (attributes as its
+arguments), so it lands in the trace's ``/host:`` plane on the clock
+the device events use; :meth:`SpanRecorder.step_span` enters a
+``StepTraceAnnotation`` instead, the profiler's marker of one loop
+iteration.  An annotation entered while no trace runs records nothing,
+so without a trace none is entered.
+
 Overhead budget: a span costs two ``time.perf_counter()`` calls, one
-lock-guarded id allocation, one lock-guarded ring write and one small
-object — single-digit microseconds, against serve decode steps of
-hundreds of microseconds (gated ≤2% in benchmarks/serve_bench.py).
-The process-global recorder in ``repro.obs`` additionally returns a
-shared no-op context manager when observability is disabled, so
-uninstrumented runs pay only a function call per span site.
+lock-guarded id allocation, one lock-guarded ring write, one small
+object and a check whether a trace runs — single-digit microseconds,
+against serve decode steps of hundreds of microseconds (gated ≤2% in
+benchmarks/serve_bench.py).  The process-global recorder in
+``repro.obs`` additionally returns a shared no-op context manager when
+observability is disabled and no trace runs, so uninstrumented runs
+pay only a function call per span site.
 
 Persistence: ``dump_jsonl`` writes one JSON object per span;
 ``export_perfetto`` emits the Chrome trace-event format
@@ -33,6 +42,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,16 +86,27 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def annotation(name: str, attrs: Dict[str, Any], step: Optional[int] = None):
+    """The profiler annotation of a span: ``StepTraceAnnotation`` when it
+    marks loop iteration ``step``, else ``TraceAnnotation``."""
+    if step is not None:
+        return StepTraceAnnotation(name, step_num=step, **attrs)
+    return TraceAnnotation(name, **attrs)
+
+
 class _ActiveSpan:
     """Context manager for one live span (see :meth:`SpanRecorder.span`)."""
 
-    __slots__ = ("_rec", "name", "attrs", "_index", "_parent", "_depth", "_t0")
+    __slots__ = ("_rec", "name", "attrs", "_step", "_ann", "_index",
+                 "_parent", "_depth", "_t0")
 
     def __init__(self, rec: "SpanRecorder", name: str,
-                 attrs: Dict[str, Any]) -> None:
+                 attrs: Dict[str, Any], step: Optional[int] = None) -> None:
         self._rec = rec
         self.name = name
         self.attrs = attrs
+        self._step = step
+        self._ann = None
 
     def __enter__(self) -> "_ActiveSpan":
         rec = self._rec
@@ -95,11 +117,17 @@ class _ActiveSpan:
         self._parent = stack[-1] if stack else -1
         self._depth = len(stack)
         stack.append(self._index)
+        if TraceAnnotation.is_enabled():
+            self._ann = annotation(self.name, self.attrs, self._step)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, *exc) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, *exc)
+            self._ann = None
         rec = self._rec
         rec._stack().pop()
         attrs = self.attrs
@@ -135,6 +163,11 @@ class SpanRecorder:
 
     def span(self, name: str, **attrs: Any) -> _ActiveSpan:
         return _ActiveSpan(self, name, attrs)
+
+    def step_span(self, name: str, step: int, **attrs: Any) -> _ActiveSpan:
+        """A span that marks loop iteration ``step`` in a profiler trace
+        (``StepTraceAnnotation``); in the ring it is a plain span."""
+        return _ActiveSpan(self, name, attrs, step)
 
     def _record(self, span: Span) -> None:
         with self._lock:

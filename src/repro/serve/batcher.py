@@ -100,6 +100,8 @@ class RequestResult:
     prefix_hit_tokens: int = 0         # prompt tokens served from the cache
     preemptions: int = 0               # times this request was preempted
     token_times: Optional[np.ndarray] = None  # per-token emission times (s)
+    # per-token host-receipt times (s): when the host first held the token
+    recv_times: Optional[np.ndarray] = None
 
     @property
     def latency(self) -> float:
@@ -205,6 +207,7 @@ class ContinuousBatcher:
         self._slot_req: List[Optional[Request]] = [None] * S
         self._emitted: List[List[int]] = [[] for _ in range(S)]
         self._emit_times: List[List[float]] = [[] for _ in range(S)]
+        self._recv_times: List[List[float]] = [[] for _ in range(S)]
         self._meta: List[Dict[str, Any]] = [{} for _ in range(S)]
         # per-slot in-progress chunked prefill: {"table", "blocks", "done"}
         self._prefill: List[Optional[Dict[str, Any]]] = [None] * S
@@ -216,7 +219,19 @@ class ContinuousBatcher:
         self.stats = {"steps": 0, "prefills": 0, "prefill_tokens": 0,
                       "prefill_chunks": 0, "preemptions": 0, "resumes": 0,
                       "active_slot_steps": 0, "context_tokens": 0,
-                      "step_walls": []}   # measured per-tick decode seconds
+                      "step_walls": [],   # measured per-tick decode seconds
+                      # host seconds by scheduler phase, each a disjoint
+                      # share of run(): admission (first-token sampling
+                      # left out), first-token sampling, prefill-chunk
+                      # dispatch, and the decode tick's three phases;
+                      # sample_first_wait_s is the part of sample_first_s
+                      # spent blocked on the device (the last chunk and
+                      # the sample), as tick_wait_s is the tick's
+                      "admit_s": 0.0, "sample_first_s": 0.0,
+                      "sample_first_wait_s": 0.0,
+                      "prefill_dispatch_s": 0.0, "tick_prepare_s": 0.0,
+                      "tick_wait_s": 0.0, "tick_emit_s": 0.0}
+        self._t0 = time.monotonic()   # run()'s clock origin
 
         # serve-side SLO metrics (repro.obs): instruments are fetched ONCE
         # here behind enabled(), so the per-tick cost while disabled is a
@@ -237,10 +252,6 @@ class ContinuousBatcher:
                                           obs.COUNT_BUCKETS)
             self._m_occ = reg.histogram("serve.pool_occupancy",
                                         obs.FRACTION_BUCKETS)
-            self._m_active = reg.histogram("serve.active_slots",
-                                           obs.COUNT_BUCKETS)
-            self._m_prefill_pending = reg.histogram(
-                "serve.prefill_pending_tokens", obs.COUNT_BUCKETS)
             self._c_decode_steps = reg.counter("serve.decode_steps")
             self._c_prefills = reg.counter("serve.prefills")
             self._c_prefill_tokens = reg.counter("serve.prefill_tokens")
@@ -261,6 +272,7 @@ class ContinuousBatcher:
                                  "hits": 0, "misses": 0, "hit_tokens": 0,
                                  "evicted": 0}
             self._pend_waits: List[Tuple[int, float]] = []
+            self._pend_itl: List[float] = []   # gaps of recv_times
 
         def step(params, pool, tables, pos, token, req_ids, tok_idx, active,
                  temps):
@@ -347,33 +359,38 @@ class ContinuousBatcher:
         worst case minus prefix-cache-matched blocks — are available,
         evicting cache blocks and preempting strictly-lower-priority
         actives to make room."""
+        t0, sampled0 = time.perf_counter(), self.stats["sample_first_s"]
         admitted = 0
-        while admitted < self.cfg.max_prefills_per_tick:
-            r = self._head(now)
-            if r is None:
-                break
-            need = self._blocks_needed(r)
-            saved = self._preempted.get(r.id)
-            # resume copies its saved K/V into fresh blocks, so it draws
-            # its full need from the free list; a fresh request re-uses
-            # matched prefix blocks in place
-            matched_blocks = 0
-            if saved is None and self._cache is not None:
-                matched_blocks = (self._cache.match_tokens(r.prompt)
-                                  // self.cfg.block_size)
-            need_free = need - matched_blocks
-            if not self._make_room(r, need_free, now):
-                break                      # head-of-line waits for room
-            slot = self._free_slot()
-            self.queue.remove(r)
-            if saved is not None:
-                del self._preempted[r.id]
-                self._resume_into(slot, r, saved, need, now)
-            elif self.cfg.prefill_chunk is not None:
-                self._begin_chunked_prefill(slot, r, need, now)
-            else:
-                self._prefill_into(slot, r, need, now)
-            admitted += 1
+        with obs.span("serve.admit"):
+            while admitted < self.cfg.max_prefills_per_tick:
+                r = self._head(now)
+                if r is None:
+                    break
+                need = self._blocks_needed(r)
+                saved = self._preempted.get(r.id)
+                # resume copies its saved K/V into fresh blocks, so it
+                # draws its full need from the free list; a fresh request
+                # re-uses matched prefix blocks in place
+                matched_blocks = 0
+                if saved is None and self._cache is not None:
+                    matched_blocks = (self._cache.match_tokens(r.prompt)
+                                      // self.cfg.block_size)
+                need_free = need - matched_blocks
+                if not self._make_room(r, need_free, now):
+                    break                  # head-of-line waits for room
+                slot = self._free_slot()
+                self.queue.remove(r)
+                if saved is not None:
+                    del self._preempted[r.id]
+                    self._resume_into(slot, r, saved, need, now)
+                elif self.cfg.prefill_chunk is not None:
+                    self._begin_chunked_prefill(slot, r, need, now)
+                else:
+                    self._prefill_into(slot, r, need, now)
+                admitted += 1
+        # the eager path samples first tokens here; sample_first_s has them
+        self.stats["admit_s"] += time.perf_counter() - t0 - (
+            self.stats["sample_first_s"] - sampled0)
         return admitted
 
     def _make_room(self, r: Request, need_free: int, now: float) -> bool:
@@ -422,13 +439,12 @@ class ContinuousBatcher:
         self.pool_state = kv_cache.scatter_prefill(
             self.pool_state, {k: v[:, 0] for k, v in kv.items()}, flat)
         first = self._sample_first(logits, r)
+        recv = self._clock()
         self.stats["prefills"] += 1
         self.stats["prefill_tokens"] += P
         if self._obs:
-            # first token is sampled at admission, so TTFT and admission
-            # wait coincide unless the request queued before a free slot
             self._m_wait.observe(max(now - r.arrival, 0.0))
-            self._m_ttft.observe(max(now - r.arrival, 0.0))
+            self._m_ttft.observe(max(recv - r.arrival, 0.0))
             self._pend_waits.append((r.priority, max(now - r.arrival, 0.0)))
             self._c_prefills.inc()
             self._c_prefill_tokens.inc(P)
@@ -444,6 +460,7 @@ class ContinuousBatcher:
         self._slot_req[slot] = r
         self._emitted[slot] = [int(first[0])]
         self._emit_times[slot] = [now]
+        self._recv_times[slot] = [recv]
         self._meta[slot] = {"admitted": now, "first_token": now,
                             "admitted_step": self.stats["steps"],
                             "need": need, "hit_tokens": 0, "preemptions": 0}
@@ -451,14 +468,23 @@ class ContinuousBatcher:
 
     def _sample_first(self, logits: jnp.ndarray, r: Request) -> np.ndarray:
         """Sample a request's first token from its prefill logits with the
-        same folded key the decode step would use at index 0."""
-        keys0 = sampling.step_keys(
-            sampling.request_keys(self.cfg.seed,
-                                  jnp.asarray([r.id], jnp.int32)), 0)
-        first_logits = logits[:, -1, :].astype(jnp.float32)
-        if self.executor is not None:
-            first_logits = self.executor.replicate_logits(first_logits)
-        return np.asarray(sampling.sample(first_logits, keys0, r.temperature))
+        same folded key the decode step would use at index 0, up to the
+        host's receipt of it."""
+        t0 = time.perf_counter()
+        with obs.span("serve.sample_first", req=r.id):
+            keys0 = sampling.step_keys(
+                sampling.request_keys(self.cfg.seed,
+                                      jnp.asarray([r.id], jnp.int32)), 0)
+            first_logits = logits[:, -1, :].astype(jnp.float32)
+            if self.executor is not None:
+                first_logits = self.executor.replicate_logits(first_logits)
+            first = sampling.sample(first_logits, keys0, r.temperature)
+            t_wait = time.perf_counter()
+            first = np.asarray(first)
+        t1 = time.perf_counter()
+        self.stats["sample_first_s"] += t1 - t0
+        self.stats["sample_first_wait_s"] += t1 - t_wait
+        return first
 
     # ------------------------------------------------------------------
     # chunked prefill
@@ -481,6 +507,7 @@ class ContinuousBatcher:
         self._slot_req[slot] = r
         self._emitted[slot] = []
         self._emit_times[slot] = []
+        self._recv_times[slot] = []
         # the slot's live table row stays TRASH until activation — the
         # decode step writes unconditionally per slot, and only the trash
         # block may absorb writes for not-yet-active slots
@@ -518,12 +545,14 @@ class ContinuousBatcher:
         n_valid = min(C, P - o)
         toks = np.zeros((1, C), np.int32)
         toks[0, :n_valid] = np.asarray(r.prompt, np.int32)[o:o + n_valid]
+        t0 = time.perf_counter()
         with obs.span("serve.prefill_chunk", req=r.id, offset=o,
                       tokens=n_valid):
             logits, self.pool_state = self._chunk_fn(
                 self._exec_params, self.pool_state,
                 jnp.asarray(st["table"]), jnp.asarray(toks),
                 jnp.int32(o), jnp.int32(n_valid))
+        self.stats["prefill_dispatch_s"] += time.perf_counter() - t0
         st["done"] = o + n_valid
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += n_valid
@@ -537,9 +566,10 @@ class ContinuousBatcher:
         cfg, st, r = self.cfg, self._prefill[slot], self._slot_req[slot]
         P = len(r.prompt)
         first = self._sample_first(logits, r)
+        recv = self._clock()
         self.stats["prefills"] += 1
         if self._obs:
-            self._m_ttft.observe(max(now - r.arrival, 0.0))
+            self._m_ttft.observe(max(recv - r.arrival, 0.0))
             self._c_prefills.inc()
             self._c_prefill_tokens.inc(P)
         if self._cache is not None:
@@ -551,6 +581,7 @@ class ContinuousBatcher:
         self._active[slot] = True
         self._emitted[slot] = [int(first[0])]
         self._emit_times[slot] = [now]
+        self._recv_times[slot] = [recv]
         self._meta[slot]["first_token"] = now
         self._prefill[slot] = None
         self._maybe_finish(slot, now)
@@ -577,6 +608,7 @@ class ContinuousBatcher:
             "tok_idx": int(self._tok_idx[slot]),
             "emitted": list(self._emitted[slot]),
             "emit_times": list(self._emit_times[slot]),
+            "recv_times": list(self._recv_times[slot]),
             "kv": kv, "meta": meta}
         self._reserved -= meta["need"] - len(blocks)
         self.pool.free_request(r.id)
@@ -586,6 +618,7 @@ class ContinuousBatcher:
         self._slot_req[slot] = None
         self._emitted[slot] = []
         self._emit_times[slot] = []
+        self._recv_times[slot] = []
         self.queue.append(r)
         self.stats["preemptions"] += 1
         log.debug("preempted request %d at pos %d", r.id, pos)
@@ -611,6 +644,7 @@ class ContinuousBatcher:
         self._slot_req[slot] = r
         self._emitted[slot] = list(saved["emitted"])
         self._emit_times[slot] = list(saved["emit_times"])
+        self._recv_times[slot] = list(saved["recv_times"])
         meta = dict(saved["meta"])
         meta["need"] = need
         self._meta[slot] = meta
@@ -636,31 +670,53 @@ class ContinuousBatcher:
                 self._tables[slot, have:have + len(new)] = new
 
     def _tick(self, now: float) -> None:
-        """One jitted decode step over all slots + host-side bookkeeping."""
-        self._grow_blocks()
-        t0 = time.perf_counter()
-        token, self.pool_state = self._step_fn(
-            self._exec_params, self.pool_state, jnp.asarray(self._tables),
-            jnp.asarray(self._pos), jnp.asarray(self._token),
-            jnp.asarray(self._req_ids), jnp.asarray(self._tok_idx),
-            jnp.asarray(self._active), jnp.asarray(self._temps))
-        token = np.asarray(token)   # device sync: the step really finished
-        self.stats["step_walls"].append(time.perf_counter() - t0)
-        self.stats["steps"] += 1
-        n_active = int(self._active.sum())
-        self.stats["active_slot_steps"] += n_active
-        self.stats["context_tokens"] += int((self._pos[self._active] + 1).sum())
-        if self._obs:
-            self._record_tick_obs(n_active)
-        for slot in range(self.cfg.slots):
-            if not self._active[slot]:
-                continue
-            self._emitted[slot].append(int(token[slot, 0]))
-            self._emit_times[slot].append(now)
-            self._token[slot] = token[slot]
-            self._pos[slot] += 1
-            self._tok_idx[slot] += 1
-            self._maybe_finish(slot, now)
+        """One jitted decode step over all slots + host-side bookkeeping,
+        in three phases, each a span and a ``stats`` counter: prepare
+        (block growth, uploads, dispatch), wait (the host blocked until
+        the sampled token arrives), emit (append, stamp and retire)."""
+        st = self.stats
+        with obs.step_span("serve.tick", st["steps"]):
+            t0 = time.perf_counter()
+            with obs.span("serve.tick.prepare"):
+                self._grow_blocks()
+                t_step = time.perf_counter()
+                token, self.pool_state = self._step_fn(
+                    self._exec_params, self.pool_state,
+                    jnp.asarray(self._tables), jnp.asarray(self._pos),
+                    jnp.asarray(self._token), jnp.asarray(self._req_ids),
+                    jnp.asarray(self._tok_idx), jnp.asarray(self._active),
+                    jnp.asarray(self._temps))
+            t1 = time.perf_counter()
+            with obs.span("serve.tick.wait"):
+                token = np.asarray(token)   # device sync: the step finished
+            t2 = time.perf_counter()
+            recv = self._clock()
+            with obs.span("serve.tick.emit"):
+                st["step_walls"].append(t2 - t_step)
+                st["steps"] += 1
+                n_active = int(self._active.sum())
+                st["active_slot_steps"] += n_active
+                st["context_tokens"] += int(
+                    (self._pos[self._active] + 1).sum())
+                for slot in range(self.cfg.slots):
+                    if not self._active[slot]:
+                        continue
+                    self._emitted[slot].append(int(token[slot, 0]))
+                    self._emit_times[slot].append(now)
+                    if self._obs:
+                        self._pend_itl.append(
+                            recv - self._recv_times[slot][-1])
+                    self._recv_times[slot].append(recv)
+                    self._token[slot] = token[slot]
+                    self._pos[slot] += 1
+                    self._tok_idx[slot] += 1
+                    self._maybe_finish(slot, now)
+                if self._obs:
+                    self._record_tick_obs(n_active)
+            t3 = time.perf_counter()
+        st["tick_prepare_s"] += t1 - t0
+        st["tick_wait_s"] += t2 - t1
+        st["tick_emit_s"] += t3 - t2
 
     def _record_tick_obs(self, n_active: int) -> None:
         """Per-tick SLO recordings: everything here is host state the
@@ -675,12 +731,8 @@ class ContinuousBatcher:
         self._m_queue.observe(len(self.queue))
         self._m_occ.observe(self.pool.num_live
                             / max(self.cfg.num_blocks - 1, 1))
-        self._m_active.observe(n_active)
         self._c_decode_steps.inc()
         self._c_decode_tokens.inc(n_active)
-        self._m_prefill_pending.observe(sum(
-            len(self._slot_req[s].prompt) - p["done"]
-            for s, p in enumerate(self._prefill) if p is not None))
         self._flush_delta(self._c_prefill_chunks, "prefill_chunks",
                           self.stats["prefill_chunks"])
         self._flush_delta(self._c_preemptions, "preemptions",
@@ -694,6 +746,8 @@ class ContinuousBatcher:
             self._flush_delta(self._c_prefix_evicted, "evicted",
                               self._cache.evicted_blocks)
         self._flush_waits()
+        self._m_itl.observe_many(self._pend_itl)   # one gap per slot
+        self._pend_itl.clear()
 
     def _flush_delta(self, counter: Any, key: str, total: int) -> None:
         d = total - self._obs_flushed[key]
@@ -728,9 +782,6 @@ class ContinuousBatcher:
         if reason is None:
             return
         meta = self._meta[slot]
-        if self._obs and len(toks) > 1:
-            self._m_itl.observe(max(now - meta["first_token"], 0.0)
-                                / (len(toks) - 1))
         self._reserved -= meta["need"] - len(self.pool.blocks_of(r.id))
         self.pool.free_request(r.id)
         self._active[slot] = False
@@ -745,11 +796,16 @@ class ContinuousBatcher:
             finished_step=self.stats["steps"], priority=r.priority,
             prefix_hit_tokens=meta.get("hit_tokens", 0),
             preemptions=meta.get("preemptions", 0),
-            token_times=np.asarray(self._emit_times[slot], np.float64))
+            token_times=np.asarray(self._emit_times[slot], np.float64),
+            recv_times=np.asarray(self._recv_times[slot], np.float64))
 
     # ------------------------------------------------------------------
     # driver
     # ------------------------------------------------------------------
+    def _clock(self) -> float:
+        """Seconds since run() started: the clock of arrivals and stamps."""
+        return time.monotonic() - self._t0
+
     def _busy(self) -> bool:
         return bool(self._active.any()) or \
             any(p is not None for p in self._prefill)
@@ -760,18 +816,17 @@ class ContinuousBatcher:
         request with ``arrival > now`` waits).  Returns results by id."""
         for r in requests or ():
             self.submit(r)
-        t0 = time.monotonic()
+        self._t0 = time.monotonic()
         while self.queue or self._busy():
-            now = time.monotonic() - t0
+            now = self._clock()
             if not self._busy() and self.queue and \
                     all(r.arrival > now for r in self.queue):
-                soonest = min(r.arrival for r in self.queue)
-                time.sleep(min(soonest - now, 0.05))
+                self._await_arrival(now)
                 continue
             admitted = self._admit(now)
-            prefilled = self._prefill_tick(time.monotonic() - t0)
+            prefilled = self._prefill_tick(self._clock())
             if self._active.any():
-                self._tick(time.monotonic() - t0)
+                self._tick(self._clock())
             elif not admitted and not prefilled:
                 # nothing running and the head could not be admitted:
                 # with no sharers left every cache block is evictable and
@@ -782,6 +837,12 @@ class ContinuousBatcher:
                     f"{self.pool.num_free} free blocks, "
                     f"{self._reserved} reserved")
         return [self.results[i] for i in sorted(self.results)]
+
+    def _await_arrival(self, now: float) -> None:
+        """Open loop, nothing in flight: sleep toward the next arrival."""
+        with obs.span("serve.await_arrival"):
+            soonest = min(r.arrival for r in self.queue)
+            time.sleep(min(soonest - now, 0.05))
 
     def defrag(self) -> int:
         """Compact live blocks to the low end of the pool; returns the

@@ -268,8 +268,8 @@ class TestBatcherMetrics:
         assert reg.get("serve.prefills").value == 5
         assert reg.get("serve.ttft_s").total == 5
         assert reg.get("serve.admission_wait_s").total == 5
-        # every request decoded >1 token, so each lands one ITL sample
-        assert reg.get("serve.inter_token_s").total == 5
+        # one ITL sample per gap between received tokens
+        assert reg.get("serve.inter_token_s").total == n_tokens - 5
         steps = reg.get("serve.decode_steps").value
         assert reg.get("serve.step_s").total == steps
         assert reg.get("serve.queue_depth").total == steps
@@ -295,9 +295,9 @@ class TestBatcherMetrics:
         assert any(r.reason == "eos" for r in results)
         assert reg.get("serve.defrags").value == 1
         assert reg.get("serve.defrag_blocks_moved").value >= 0
-        # retired-early requests with a single token never record an ITL
+        # one ITL sample per gap: a single-token request records none
         itl = reg.get("serve.inter_token_s")
-        assert itl.total == sum(1 for r in results if len(r.tokens) > 1)
+        assert itl.total == sum(len(r.tokens) - 1 for r in results)
 
     def test_tokens_bitwise_identical_with_obs(self, tiny):
         """The whole point of the overhead gate: instrumentation must be

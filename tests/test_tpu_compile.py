@@ -11,8 +11,11 @@ Widths are opt125m-proxy's (d_model 768, d_ff 3072, head_dim 64), plus
 the paged decode kernel at head_dim 128 with 8 kv heads (internlm2-20b's
 GQA shape; the kernel's ``head_dim >= 128`` gate keeps head_dim 64 on the
 reference gather).  Each compiled program must contain the kernel as a
-``tpu_custom_call``.
+``tpu_custom_call``, and the kernels the benchmark's trace reduction
+finds by name (``bench/lib/trace.KERNELS``) must keep that name.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -55,6 +58,12 @@ def _compile_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _named(text: str, kernel: str) -> bool:
+    """The kernel's custom call is the HLO instruction ``%<kernel>.N``."""
+    return re.search(rf"%{kernel}(?:\.\d+)? = .*custom-call\(", text) \
+        is not None
+
+
 def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -74,6 +83,7 @@ def test_flash_attention(one_chip):
         lambda q, k, v: flash_attention.flash_attention(
             q, k, v, causal=True, bq=512, bk=512), q, q, q)
     assert "tpu_custom_call" in text
+    assert _named(text, "flash_attention")
 
 
 @pytest.mark.parametrize("rows", [4, 256])   # decode slots, a prefill chunk
@@ -84,6 +94,7 @@ def test_spmm24(one_chip, rows, m, n):
         _sds(one_chip, (rows, n), BF16), _sds(one_chip, (m, n // 2), BF16),
         _sds(one_chip, (m, n // 4), jnp.uint8))
     assert "tpu_custom_call" in text
+    assert _named(text, "spmm24")
 
 
 def test_fused_mlp24_gelu(one_chip):
@@ -96,6 +107,7 @@ def test_fused_mlp24_gelu(one_chip):
         _sds(one_chip, (d, f // 2), BF16), _sds(one_chip, (d, f // 4), jnp.uint8),
         _sds(one_chip, (d,), BF16))
     assert "tpu_custom_call" in text
+    assert _named(text, "fused_mlp24")
 
 
 def test_paged_decode_attn_gqa_hd128(one_chip):
@@ -108,3 +120,4 @@ def test_paged_decode_attn_gqa_hd128(one_chip):
         _sds(one_chip, (slots, cols), jnp.int32),
         _sds(one_chip, (slots,), jnp.int32), _sds(one_chip, (slots,), jnp.bool_))
     assert "tpu_custom_call" in text
+    assert _named(text, "paged_decode_attn")
