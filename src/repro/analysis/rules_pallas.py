@@ -254,6 +254,8 @@ def check_site(case: KernelCase, site: PallasSite) -> List[Finding]:
                                 tuple(struct.shape), struct.dtype,
                                 f"out[{i}]", findings)
     for ref in site.scratch_shapes:
+        if str(getattr(ref, "memory_space", "vmem")) != "vmem":
+            continue            # semaphores and SMEM scratch hold no VMEM
         vmem += int(np.prod(tuple(ref.shape), dtype=np.int64)) * \
             np.dtype(ref.dtype).itemsize
 
@@ -355,6 +357,19 @@ def _build_paged() -> None:
     mod.paged_decode_attn(q, pool, pool, tables, pos, active, block_size=bs)
 
 
+def _build_paged_lanes() -> None:
+    import jax.numpy as jnp
+    from repro.kernels import paged_attention as mod
+    S, nq, nkv, hd, bs, nblocks = 2, 4, 2, 64, 16, 8
+    q = jnp.zeros((S, nq, hd), jnp.float32)
+    pool = jnp.zeros((nblocks * bs, nkv * hd), jnp.float32)
+    tables = jnp.asarray([[1, 2, 3, 0], [4, 5, 6, 7]], jnp.int32)
+    pos = jnp.asarray([40, 63], jnp.int32)
+    active = jnp.asarray([1, 1], jnp.int32)
+    mod.paged_decode_attn_lanes(q, pool, pool, tables, pos, active,
+                                block_size=bs, blocks=2)
+
+
 def _build_fused_mlp() -> None:
     import jax.numpy as jnp
     from repro.kernels import paged_attention as mod
@@ -379,6 +394,10 @@ KERNEL_CASES: List[KernelCase] = [
     KernelCase("paged_attention", "src/repro/kernels/paged_attention.py",
                "paged_decode_attn", "paged_attention", 4 * 2**20,
                _build_paged),
+    KernelCase("paged_attention_lanes",
+               "src/repro/kernels/paged_attention.py",
+               "paged_decode_attn_lanes", "paged_attention", 4 * 2**20,
+               _build_paged_lanes),
     KernelCase("fused_mlp24", "src/repro/kernels/paged_attention.py",
                "fused_mlp24", "fused_mlp24", 8 * 2**20, _build_fused_mlp),
 ]

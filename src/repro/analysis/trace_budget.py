@@ -82,6 +82,7 @@ TRACE_BUDGETS: Dict[str, int] = {
     "repro.kernels.fista_step:fista_prox_step": 8,
     "repro.kernels.flash_attention:flash_attention": 8,
     "repro.kernels.paged_attention:paged_decode_attn": 8,
+    "repro.kernels.paged_attention:paged_decode_attn_lanes": 8,
     "repro.kernels.paged_attention:fused_mlp24": 8,
     # -- mesh substrate: one executable per cached (fn, spec) key ------
     "repro.distributed.executor:MeshExecutor.sharded_group_stats.<locals>.build": 2,

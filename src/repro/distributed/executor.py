@@ -184,19 +184,25 @@ class MeshExecutor:
             tree, jax.tree_util.tree_map(
                 lambda _: NamedSharding(self.mesh, P()), tree))
 
-    def shard_paged_pool(self, pool: Any) -> Any:
+    def shard_paged_pool(self, pool: Any, num_kv_heads: int) -> Any:
         """Heads-sharded device layout of the paged KV pool: the
         (L, num_blocks*block_size, nkv, hd) tensors shard ``nkv`` over
-        "model" (each model shard holds its attention heads' pages —
-        the decode gather/scatter is then fully local per shard and the
-        one all-reduce per block lands after wo).  Falls back to
-        replication when nkv does not divide the axis (MQA)."""
+        "model", and the (L, num_blocks*block_size, nkv*hd) ones their
+        rows, whole heads to a shard (each model shard holds its
+        attention heads' pages — the decode gather/scatter is then fully
+        local per shard and the one all-reduce per block lands after
+        wo).  Falls back to replication when nkv does not divide the
+        axis (MQA)."""
+        whole = num_kv_heads % self.model_size == 0
 
         def spec(leaf):
-            if getattr(leaf, "ndim", 0) == 4:
+            ndim = getattr(leaf, "ndim", 0)
+            if ndim == 4:
                 return sharding._fit_spec(self.mesh,
                                           P(None, None, "model", None),
                                           leaf.shape)
+            if ndim == 3 and whole:
+                return P(None, None, "model")
             return P()
 
         return jax.device_put(

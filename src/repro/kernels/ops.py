@@ -14,6 +14,7 @@ back to the oracle, where kernel launch overhead would dominate.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,9 @@ def _interpret() -> bool:
 
 _MIN_PALLAS_DIM = 128  # below this, use the jnp oracle
 _FUSED_MLP_BF = 512    # d_ff tile: keeps the (d, bf/4) meta block lane-aligned
+# context tokens per inner step of the lane-dense decode: on a TPU v5e,
+# 128 and 256 tie at the batch cell's shapes, 64 and 512 are slower
+_DECODE_LANE_TOKENS = 128
 
 
 def fista_prox_step(y: jnp.ndarray, G: jnp.ndarray, B: jnp.ndarray,
@@ -71,13 +75,43 @@ unpack24 = ref.unpack24
 # ---------------------------------------------------------------------------
 # fused decode fast path (kernels/paged_attention.py)
 # ---------------------------------------------------------------------------
+def pool_row_shape(num_kv_heads: int, head_dim: int) -> tuple:
+    """Shape of one token's K (or V) row in the paged KV pool.
+
+    Heads narrower than a lane row sit side by side in one row of
+    ``nkv*hd``: kept as ``(nkv, hd)``, the TPU would pad every head to
+    128 lanes or lay the whole pool out token-minor, and neither can be
+    read one block at a time (``paged_attention.paged_decode_attn_lanes``
+    reads a block as one ``(block_size, nkv*hd)`` tile).  Heads of 128
+    lanes or more keep ``(nkv, hd)``.  Shape only, on every backend, so
+    CPU runs the layout the chip runs.
+    """
+    if head_dim < _MIN_PALLAS_DIM:
+        return (num_kv_heads * head_dim,)
+    return (num_kv_heads, head_dim)
+
+
 def use_decode_kernel(head_dim: int, block_size: int) -> bool:
-    """True when the block-table decode kernels compile for these shapes:
+    """True when the block-table decode kernel for head_dim >= 128
+    (``paged_attention.paged_decode_attn``) compiles for these shapes:
     TPU backend, lane-width head_dim, sublane-aligned block_size.  When
-    False the fused decode path runs the ``ref.py`` oracles — which on
-    CPU is exactly the reference gather math, keeping fused == reference
-    bitwise (DESIGN.md §11 fallback rules)."""
+    neither this nor :func:`use_decode_lanes` holds, the fused decode
+    path runs the ``ref.py`` oracle — which on CPU is exactly the
+    reference gather math, keeping fused == reference bitwise
+    (DESIGN.md §11 fallback rules)."""
     return (not _interpret()) and head_dim >= _MIN_PALLAS_DIM \
+        and block_size % 8 == 0
+
+
+def use_decode_lanes(head_dim: int, num_kv_heads: int,
+                     block_size: int) -> bool:
+    """True when the lane-dense decode kernel
+    (``paged_attention.paged_decode_attn_lanes``) takes these shapes:
+    TPU backend, head_dim below the lane width, the kv heads of one
+    token filling whole 128-lane rows side by side, and a
+    sublane-aligned block_size."""
+    return (not _interpret()) and head_dim < _MIN_PALLAS_DIM \
+        and (num_kv_heads * head_dim) % _MIN_PALLAS_DIM == 0 \
         and block_size % 8 == 0
 
 
@@ -85,15 +119,24 @@ def paged_decode_attn(q, k_pool, v_pool, tables, pos, active, *,
                       block_size: int, window: int = 0, softcap: float = 0.0):
     """Block-table flash decode -> (S, nq, hd) in q.dtype.
 
-    Kernel on TPU-compilable shapes, ``ref.paged_attention`` otherwise.
+    A kernel on TPU-compilable shapes (head_dim >= 128, or the
+    lane-dense one below it), ``ref.paged_attention`` otherwise.
     """
-    if not use_decode_kernel(q.shape[-1], block_size):
-        return ref.paged_attention(q, k_pool, v_pool, tables, pos, active,
-                                   block_size=block_size, window=window,
-                                   softcap=softcap)
-    return _paged.paged_decode_attn(q, k_pool, v_pool, tables, pos, active,
-                                    block_size=block_size, window=window,
-                                    softcap=softcap, interpret=False)
+    hd = q.shape[-1]
+    if use_decode_kernel(hd, block_size):
+        return _paged.paged_decode_attn(q, k_pool, v_pool, tables, pos,
+                                        active, block_size=block_size,
+                                        window=window, softcap=softcap,
+                                        interpret=False)
+    if use_decode_lanes(hd, math.prod(k_pool.shape[1:]) // hd, block_size):
+        return _paged.paged_decode_attn_lanes(
+            q, k_pool, v_pool, tables, pos, active, block_size=block_size,
+            window=window, softcap=softcap,
+            blocks=max(1, _DECODE_LANE_TOKENS // block_size),
+            interpret=False)
+    return ref.paged_attention(q, k_pool, v_pool, tables, pos, active,
+                               block_size=block_size, window=window,
+                               softcap=softcap)
 
 
 def use_fused_mlp(d_model: int, d_ff: int) -> bool:

@@ -21,6 +21,15 @@ grid-native: head ``h`` attends with its ``g = nq/nkv`` query heads, so
 repeated KV heads are never materialized, and a tensor-parallel mesh
 shards ``nkv`` before the call (see ``models/common._paged_attn_sharded``).
 
+``paged_decode_attn_lanes`` is the same walk for head_dim < 128, where a
+per-head tile would fill half a lane row or less.  The pool stores a
+token's kv heads side by side in one row of ``nkv*hd`` lanes
+(``ops.pool_row_shape``), so a pool block is one lane-dense
+``(block_size, nkv*hd)`` tile.  Grid ``(S,)``: each slot copies in only
+its live blocks, from the window's first to ``pos // block_size``,
+``blocks`` at a time by manual double-buffered DMA, and scores all its
+query heads at once against a block-diagonal query.
+
 ``fused_mlp24`` runs the whole decode MLP (gate/up/down or fc1/fc2) over
 the packed-2:4 store (``serve/packed.py``) in ONE pallas_call, grid over
 d_ff tiles: every packed operand tile is rebuilt in VMEM with the same
@@ -152,6 +161,172 @@ def paged_decode_attn(q: jnp.ndarray, k_pool: jnp.ndarray,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32),
       active.astype(jnp.int32), q4, kb, vb)
     return out.reshape(S, nq, hd)
+
+
+# ---------------------------------------------------------------------------
+# block-table flash decode attention with lane-dense heads (head_dim < 128)
+# ---------------------------------------------------------------------------
+def _lanes_kernel(tab_ref, pos_ref, act_ref, q_ref, k_hbm, v_hbm, out_ref,
+                  kbuf, vbuf, sem, *, scale: float, block_size: int,
+                  blocks: int, cols: int, window: int, softcap: float):
+    s = pl.program_id(0)
+    nq = q_ref.shape[1]
+    tokens = blocks * block_size
+
+    @pl.when(s == 0)
+    def _zero():
+        # lanes past a slot's last live block keep what an earlier copy
+        # left (or this zero): their probabilities are exactly 0, and
+        # 0 * finite stays 0
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    p = pos_ref[s]
+    hi = jnp.minimum(p // block_size, cols - 1)      # last live column
+    lo = jnp.maximum(p - window + 1, 0) // block_size if window > 0 else 0
+    steps = jnp.where(act_ref[s] > 0, (hi - lo) // blocks + 1, 0)
+
+    def copies(i, slot):
+        """(live, K copy, V copy) for each table column of step i."""
+        out = []
+        for b in range(blocks):
+            c = lo + i * blocks + b
+            blk = tab_ref[s * cols + jnp.minimum(c, cols - 1)]
+            dst = pl.ds(b * block_size, block_size)
+            out.append((c <= hi,
+                        pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[slot, dst],
+                                              sem.at[slot, 0]),
+                        pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[slot, dst],
+                                              sem.at[slot, 1])))
+        return out
+
+    def start(i, slot):
+        for live, ck, cv in copies(i, slot):
+            @pl.when(live)
+            def _():
+                ck.start()
+                cv.start()
+
+    def wait(i, slot):
+        for live, ck, cv in copies(i, slot):
+            @pl.when(live)
+            def _():
+                ck.wait()
+                cv.wait()
+
+    @pl.when(steps > 0)
+    def _first():
+        start(0, 0)
+
+    qb = q_ref[0]                                      # (nq, nkv*hd)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nq, tokens), 1)
+
+    def body(i, carry):
+        m_prev, l_prev, acc = carry
+        slot = i % 2
+
+        @pl.when(i + 1 < steps)
+        def _next():
+            start(i + 1, 1 - slot)
+
+        wait(i, slot)
+        k = kbuf[slot]                                 # (tokens, nkv*hd)
+        v = vbuf[slot]
+        # q is block-diagonal over heads, so one product gives every
+        # query head's scores against its own kv head's lanes
+        sc = jax.lax.dot_general(qb, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
+        if softcap > 0:
+            sc = jnp.tanh(sc / softcap) * softcap
+        tok = (lo + i * blocks) * block_size + lane
+        valid = tok <= p
+        if window > 0:
+            valid &= tok > p - window
+        sc = jnp.where(valid, sc, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        pr = jnp.exp(sc - m_new)                       # (nq, tokens)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + jnp.sum(pr, axis=1, keepdims=True)
+        # row j holds head j's output in its kv head's lanes; the other
+        # lanes are finite by-products, never stored
+        acc = acc * alpha + jax.lax.dot_general(
+            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    init = (jnp.full((nq, 1), NEG_INF, jnp.float32),
+            jnp.zeros((nq, 1), jnp.float32),
+            jnp.zeros(qb.shape, jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, steps, body, init)
+    o = acc / jnp.maximum(l, 1e-30)
+    hd = out_ref.shape[2]
+    g = nq // (qb.shape[1] // hd)
+    for h in range(nq // g):                   # each head's own lanes
+        out_ref[0, h * g:(h + 1) * g] = \
+            o[h * g:(h + 1) * g, h * hd:(h + 1) * hd].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "window",
+                                             "softcap", "blocks",
+                                             "interpret"))
+def paged_decode_attn_lanes(q: jnp.ndarray, k_pool: jnp.ndarray,
+                            v_pool: jnp.ndarray, tables: jnp.ndarray,
+                            pos: jnp.ndarray, active: jnp.ndarray, *,
+                            block_size: int, window: int = 0,
+                            softcap: float = 0.0, blocks: int = 8,
+                            interpret: bool = False) -> jnp.ndarray:
+    """Block-table flash decode for head_dim < 128: same contract as
+    :func:`paged_decode_attn`, for pools of (T, nkv*hd) rows
+    (``kernels.ops.pool_row_shape``; (T, nkv, hd) is read the same way).
+
+    A pool block is read as one ``(block_size, nkv*hd)`` tile (whole
+    lanes, every kv head side by side) by a manual DMA from the pool in
+    HBM, ``blocks`` table columns per inner step, and only the columns
+    from the window's first to ``pos // block_size``: a slot reads
+    nothing past its last live block, and an inactive slot reads
+    nothing.  The scores of all query heads come from one product of a
+    block-diagonal ``(nq, nkv*hd)`` query with the K tile, accumulated in
+    float32; the online softmax runs in float32 per query head, and its
+    probabilities meet V in the pool's dtype, as the reference path's do.
+    """
+    S, nq, hd = q.shape
+    T = k_pool.shape[0]
+    D = int(np.prod(k_pool.shape[1:]))
+    MB = tables.shape[1]
+    nkv = D // hd
+    g = nq // nkv
+    own = (jnp.arange(nq) // g)[:, None] == (jnp.arange(D) // hd)[None, :]
+    qb = jnp.where(own, jnp.tile(q, (1, 1, nkv)), 0).astype(k_pool.dtype)
+    kb = k_pool.reshape(T // block_size, block_size, D)
+    vb = v_pool.reshape(T // block_size, block_size, D)
+
+    kernel = functools.partial(
+        _lanes_kernel, scale=1.0 / np.sqrt(hd), block_size=block_size,
+        blocks=blocks, cols=MB, window=int(window or 0),
+        softcap=float(softcap))
+    tile = (2, blocks * block_size, D)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, nq, D), lambda s, tab, p, a: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, nq, hd), lambda s, tab, p, a: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM(tile, k_pool.dtype),
+            pltpu.VMEM(tile, v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ])
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, nq, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="paged_decode_attn",
+    )(tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
+      active.astype(jnp.int32), qb, kb, vb)
 
 
 # ---------------------------------------------------------------------------

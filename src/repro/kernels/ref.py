@@ -78,9 +78,10 @@ def paged_attention(q, k_pool, v_pool, tables, pos, active, *,
                     block_size: int, window: int = 0, softcap: float = 0.0):
     """Block-table decode attention oracle (kernels/paged_attention.py).
 
-    q (S, nq, hd) post-RoPE queries; pools (T, nkv, hd) flat block pools
-    with the current token's K/V already written; tables (S, MB) int32;
-    pos (S,) absolute positions; active (S,) bool.  Returns (S, nq, hd).
+    q (S, nq, hd) post-RoPE queries; pools (T, nkv, hd) or (T, nkv*hd)
+    flat block pools with the current token's K/V already written;
+    tables (S, MB) int32; pos (S,) absolute positions; active (S,) bool.
+    Returns (S, nq, hd).
 
     Element-for-element the reference gather path: the table row is
     expanded to the same position-order ``gather_idx`` that
@@ -92,15 +93,15 @@ def paged_attention(q, k_pool, v_pool, tables, pos, active, *,
     import numpy as np
     S, MB = tables.shape
     nq, hd = q.shape[1], q.shape[2]
-    nkv = k_pool.shape[1]
+    nkv = int(np.prod(k_pool.shape[1:])) // hd
     g = nq // nkv
     W = MB * block_size
     j = jnp.arange(W, dtype=jnp.int32)
     blocks = jnp.take_along_axis(tables, jnp.broadcast_to(j // block_size,
                                                           (S, W)), axis=1)
     gather_idx = blocks * block_size + (j % block_size)[None, :]
-    kg = jnp.take(k_pool, gather_idx, axis=0)                # (S,W,nkv,hd)
-    vg = jnp.take(v_pool, gather_idx, axis=0)
+    kg = jnp.take(k_pool, gather_idx, axis=0).reshape(S, W, nkv, hd)
+    vg = jnp.take(v_pool, gather_idx, axis=0).reshape(S, W, nkv, hd)
     idx = jnp.arange(W, dtype=jnp.int32)
     valid = (idx[None, :] <= pos[:, None]) & active[:, None]
     if window:

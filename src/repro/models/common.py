@@ -149,6 +149,13 @@ def _split_heads(x: jnp.ndarray, n: int, hd: int) -> jnp.ndarray:
     return x.reshape(x.shape[:-1] + (n, hd))
 
 
+def _pool_rows(x: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
+    """(..., nkv, hd) K/V rows in the paged pool's row shape and dtype:
+    the pool keeps ``(nkv, hd)`` or, below the lane width, ``(nkv*hd,)``
+    (``kernels.ops.pool_row_shape``)."""
+    return x.reshape(x.shape[:-2] + pool.shape[1:]).astype(pool.dtype)
+
+
 def _causal_window_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray, window: Optional[int],
                         causal: bool = True) -> jnp.ndarray:
     """(..., Sq, Sk) boolean mask. window w => attend to (i-w, i]."""
@@ -370,18 +377,20 @@ def _paged_attn_sharded(q: jnp.ndarray, k_pool: jnp.ndarray,
             window=window, softcap=softcap)
 
     mesh = jax.sharding.get_abstract_mesh()
-    nkv = k_pool.shape[1]
+    nkv = int(np.prod(k_pool.shape[1:])) // q.shape[-1]
     if ("model" not in mesh.axis_names
             or nkv % mesh.shape["model"] != 0):
         return local(q, k_pool, v_pool, tables, pos, active)
     from jax.sharding import PartitionSpec as P
 
     # q heads shard group-aligned with kv heads: nkv % msize == 0 makes
-    # every "model" shard's contiguous q chunk a whole set of kv groups
+    # every "model" shard's contiguous q chunk a whole set of kv groups,
+    # and its slice of a (T, nkv*hd) pool row whole heads
+    pool_spec = P(None, "model", *([None] * (k_pool.ndim - 2)))
     fn = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(None, "model", None), P(None, "model", None),
-                  P(None, "model", None), P(None, None), P(None), P(None)),
+        in_specs=(P(None, "model", None), pool_spec, pool_spec,
+                  P(None, None), P(None), P(None)),
         out_specs=P(None, "model", None),
         check_vma=False)
     return fn(q, k_pool, v_pool, tables, pos, active)
@@ -400,7 +409,8 @@ def mha_decode_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     x: (S, 1, D) one token per serving slot; pos: (S,) per-slot absolute
     positions (unlike :func:`mha_decode`, slots decode at independent
     positions); cache: ``{"k", "v"}`` flat block pool for this layer,
-    shape (T, nkv, hd) with T = num_blocks * block_size; write_idx: (S,)
+    shape (T, nkv, hd) or (T, nkv*hd) (``kernels.ops.pool_row_shape``)
+    with T = num_blocks * block_size; write_idx: (S,)
     flat pool slot receiving this token's K/V; gather_idx: (S, W) flat
     pool slots of each slot's context *in position order*; active: (S,)
     bool — inactive slots write to the trash block and attend to
@@ -433,8 +443,8 @@ def mha_decode_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     if cfg.partial_rotary > 0:
         q = apply_rope(q, pos_b, inv)
         k_new = apply_rope(k_new, pos_b, inv)
-    k = cache["k"].at[write_idx].set(k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[write_idx].set(v_new[:, 0].astype(cache["v"].dtype))
+    k = cache["k"].at[write_idx].set(_pool_rows(k_new[:, 0], cache["k"]))
+    v = cache["v"].at[write_idx].set(_pool_rows(v_new[:, 0], cache["v"]))
     new_cache = {"k": k, "v": v}
     if impl == "fused" and tables is not None:
         o = _paged_attn_sharded(q[:, 0], k, v, tables, pos, active,
@@ -442,8 +452,9 @@ def mha_decode_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
                                 float(cfg.attn_logit_softcap))
         out = o.reshape(o.shape[0], 1, nq * hd)
         return dense(out, p["wo"]), new_cache
-    kg = jnp.take(k, gather_idx, axis=0)                          # (S,W,nkv,hd)
-    vg = jnp.take(v, gather_idx, axis=0)
+    heads = gather_idx.shape + (nkv, hd)
+    kg = jnp.take(k, gather_idx, axis=0).reshape(heads)          # (S,W,nkv,hd)
+    vg = jnp.take(v, gather_idx, axis=0).reshape(heads)
     idx = jnp.arange(gather_idx.shape[1], dtype=jnp.int32)
     valid = decode_window_mask(idx[None, :], pos[:, None], window) \
         & active[:, None]
@@ -468,8 +479,8 @@ def mha_prefill_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
 
     x: (1, C, D) post-ln1 hidden of one prompt chunk for a single
     request; pos: (C,) absolute positions of the chunk rows; cache:
-    this layer's flat block pool (T, nkv, hd); write_idx: (C,) flat
-    pool slot per row — padded rows (beyond the caller's ``n_valid``)
+    this layer's flat block pool, (T, nkv, hd) or (T, nkv*hd); write_idx:
+    (C,) flat pool slot per row — padded rows (beyond the caller's ``n_valid``)
     point into the trash block; gather_idx: (W,) flat slots of the
     request's full fixed-width context in position order, W = table
     width * block_size.
@@ -495,11 +506,12 @@ def mha_prefill_paged(cfg: ModelConfig, p: Params, x: jnp.ndarray,
         pos_b = pos[None, :]                                      # (1,C)
         q = apply_rope(q, pos_b, inv)
         k_new = apply_rope(k_new, pos_b, inv)
-    k = cache["k"].at[write_idx].set(k_new[0].astype(cache["k"].dtype))
-    v = cache["v"].at[write_idx].set(v_new[0].astype(cache["v"].dtype))
+    k = cache["k"].at[write_idx].set(_pool_rows(k_new[0], cache["k"]))
+    v = cache["v"].at[write_idx].set(_pool_rows(v_new[0], cache["v"]))
     new_cache = {"k": k, "v": v}
-    kg = jnp.take(k, gather_idx, axis=0)                          # (W,nkv,hd)
-    vg = jnp.take(v, gather_idx, axis=0)
+    heads = gather_idx.shape + (nkv, hd)
+    kg = jnp.take(k, gather_idx, axis=0).reshape(heads)          # (W,nkv,hd)
+    vg = jnp.take(v, gather_idx, axis=0).reshape(heads)
     idx = jnp.arange(gather_idx.shape[0], dtype=jnp.int32)
     valid = decode_window_mask(idx[None, :], pos[:, None], window)  # (C,W)
     qg = q.reshape(1, C, nkv, g, hd)

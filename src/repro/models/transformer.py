@@ -271,12 +271,15 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jnp.ndarray,
 # ---------------------------------------------------------------------------
 def init_paged_caches(cfg: ModelConfig, num_blocks: int, block_size: int
                       ) -> Dict[str, jnp.ndarray]:
-    """Paged KV pool: one flat (L, num_blocks*block_size, nkv, hd) tensor
-    per K/V.  Block ``b``, offset ``s`` lives at flat slot
-    ``b*block_size + s``; block 0 is the serving stack's reserved trash
-    block (``serve/kv_cache.py``) — inactive slots write there."""
-    hd = cfg.resolved_head_dim()
-    shape = (cfg.num_layers, num_blocks * block_size, cfg.num_kv_heads, hd)
+    """Paged KV pool: one flat (L, num_blocks*block_size, *row) tensor
+    per K/V, a token's row being ``(nkv, hd)``, or ``(nkv*hd,)`` for
+    heads narrower than a lane row (``kernels.ops.pool_row_shape``).
+    Block ``b``, offset ``s`` lives at flat slot ``b*block_size + s``;
+    block 0 is the serving stack's reserved trash block
+    (``serve/kv_cache.py``) — inactive slots write there."""
+    from repro.kernels.ops import pool_row_shape
+    row = pool_row_shape(cfg.num_kv_heads, cfg.resolved_head_dim())
+    shape = (cfg.num_layers, num_blocks * block_size) + row
     dt = dtype_of(cfg.compute_dtype)
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 

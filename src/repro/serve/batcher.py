@@ -190,7 +190,8 @@ class ContinuousBatcher:
             self.params = executor.shard_params(self.params)
             exec_params = self.params if same else \
                 executor.shard_params(exec_params)
-            self.pool_state = executor.shard_paged_pool(self.pool_state)
+            self.pool_state = executor.shard_paged_pool(
+                self.pool_state, model.cfg.num_kv_heads)
         self._exec_params = exec_params
         self._cache: Optional[PrefixCache] = (
             PrefixCache(self.pool, cfg.prefix_cache_blocks)

@@ -197,7 +197,8 @@ class Engine:
                 o += n_valid
             firsts.append(last[:, -1, :])
             for k in state:
-                rows[k].append(pool[k][:, flat])
+                rows[k].append(pool[k][:, flat].reshape(
+                    (-1, len(flat)) + state[k].shape[3:]))
         # cache_len >= P, so decode's non-ring slots are the absolute
         # positions: rows land at 0..P-1, the tail stays zero (masked)
         state = {k: state[k].at[:, :, :P].set(jnp.stack(rows[k], axis=1))

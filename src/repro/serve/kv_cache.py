@@ -190,10 +190,14 @@ def table_row(blocks: Sequence[int], max_blocks: int) -> np.ndarray:
 def scatter_prefill(pool: Dict[str, Any], kv: Dict[str, Any],
                     flat_idx: np.ndarray) -> Dict[str, Any]:
     """Write a prefill's K/V rows ``(L, P, nkv, hd)`` into pool slots
-    ``flat_idx`` (P,).  Values are cast to the pool dtype — the same cast
+    ``flat_idx`` (P,), in the pool's row shape (``(nkv, hd)`` or
+    ``(nkv*hd,)``).  Values are cast to the pool dtype — the same cast
     the contiguous serve cache applies, keeping the paged read bitwise
     equal to the contiguous one."""
-    return {name: pool[name].at[:, flat_idx].set(kv[name].astype(pool[name].dtype))
+    def rows(x, t):
+        return x.reshape(x.shape[:2] + t.shape[2:]).astype(t.dtype)
+
+    return {name: pool[name].at[:, flat_idx].set(rows(kv[name], pool[name]))
             for name in pool}
 
 

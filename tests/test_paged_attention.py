@@ -9,6 +9,9 @@ code is:
 * Pallas kernels vs. the oracle under ``interpret=True`` (the
   ``kernels_interpret`` marker; compiled-mode parity needs a TPU),
   including GQA with several kv heads per pool block and the fused MLP;
+* the lane-dense kernel for head_dim < 128 at OPT-125M's head shapes
+  (head_dim 64, 12 kv heads, blocks of 16) vs. the float64 loop and the
+  oracle, with every block a slot must not read poisoned with NaN;
 * the serving contract: ``impl="fused"`` is BITWISE the reference
   gather path on this backend (DESIGN.md §11), at the attention level
   and through a full multi-step ``paged_serve_step`` drive — dense and
@@ -65,19 +68,21 @@ def build_scenario(seed, lengths, nkv=2, g=2, hd=8, trash_fill=37.0):
 
 
 def naive_paged_attention(q, k_pool, v_pool, tables, pos, active,
-                          window=0, softcap=0.0):
+                          window=0, softcap=0.0, block_size=BS):
     """Per-slot, per-head loop-and-softmax in float64 — the independent
     check the oracle (and through it the kernel) is pinned against.
     Inactive slots return zeros (their serving output is discarded)."""
     S, nq, hd = q.shape
-    nkv = k_pool.shape[1]
+    nkv = int(np.prod(k_pool.shape[1:])) // hd
     g = nq // nkv
+    k_pool = k_pool.reshape(-1, nkv, hd)
+    v_pool = v_pool.reshape(-1, nkv, hd)
     out = np.zeros_like(q)
     for s in range(S):
         if not active[s]:
             continue
         lo = max(0, pos[s] - window + 1) if window else 0
-        flat = [tables[s, t // BS] * BS + t % BS
+        flat = [tables[s, t // block_size] * block_size + t % block_size
                 for t in range(lo, pos[s] + 1)]
         k, v = k_pool[flat].astype(np.float64), v_pool[flat].astype(np.float64)
         for h in range(nkv):
@@ -217,6 +222,112 @@ class TestKernelInterpret:
                                    rtol=1e-5, atol=1e-6)
 
 
+LANE_BS = 16    # OPT-125M's serving block size
+
+
+def build_lane_scenario(seed, lengths, cols, nkv=12, g=1, hd=64, window=0,
+                        poison=True):
+    """Pool rows of ``nkv*hd`` (the pool layout below head_dim 128) for
+    ragged contexts of ``lengths`` in tables ``cols`` wide.
+
+    Every block a slot must not read holds NaN when ``poison`` (else
+    large finite garbage): the trash block, the blocks its table's tail
+    columns point at (each its own block, the last column the trash
+    block), and under a window its blocks wholly before the window.
+    Returns numpy (q, k_pool, v_pool, tables, pos), pos = lengths - 1.
+    """
+    rng = np.random.default_rng(seed)
+    S, D = len(lengths), nkv * hd
+    nb = 1 + S * cols
+    perm = rng.permutation(np.arange(1, nb))
+    tables = perm.reshape(S, cols).astype(np.int32)
+    k = rng.standard_normal((nb * LANE_BS, D)).astype(np.float32)
+    v = rng.standard_normal((nb * LANE_BS, D)).astype(np.float32)
+    dead = [TRASH]
+    for s, L in enumerate(lengths):
+        live = -(-L // LANE_BS)
+        if live < cols:
+            tables[s, -1] = TRASH
+        first = max(0, L - window) // LANE_BS if window else 0
+        dead += list(tables[s, live:]) + list(tables[s, :first])
+    for b in dead:
+        k[b * LANE_BS:(b + 1) * LANE_BS] = np.nan if poison else 37.0
+        v[b * LANE_BS:(b + 1) * LANE_BS] = np.nan if poison else -37.0
+    q = rng.standard_normal((S, nkv * g, hd)).astype(np.float32)
+    return q, k, v, tables, np.asarray(lengths, np.int32) - 1
+
+
+@pytest.mark.kernels_interpret
+class TestLaneKernelInterpret:
+    """``paged_decode_attn_lanes`` (head_dim < 128) under
+    ``interpret=True``: OPT-125M's heads (hd 64, 12 kv heads, blocks of
+    16).  Lengths end at a block's first token, mid-block, on a block's
+    last token and on the table's last column; two slots have fewer
+    live columns than one inner step takes; one slot is inactive."""
+
+    LENGTHS = [1, 16, 17, 100, 160, 33]
+    ACTIVE = [True, True, True, True, True, False]
+    COLS = 10
+
+    def _run(self, q, k, v, tables, pos, blocks, **kw):
+        return np.asarray(pk.paged_decode_attn_lanes(
+            jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+            jnp.asarray(tables), jnp.asarray(pos),
+            jnp.asarray(self.ACTIVE), block_size=LANE_BS, blocks=blocks,
+            interpret=True, **kw))
+
+    @pytest.mark.parametrize("window,softcap", [(0, 0.0), (40, 0.0),
+                                                (0, 5.0), (37, 2.0)])
+    def test_matches_naive_and_oracle(self, window, softcap):
+        on = np.asarray(self.ACTIVE)
+        scen = build_lane_scenario(10, self.LENGTHS, self.COLS,
+                                   window=window)
+        got = self._run(*scen, blocks=4, window=window, softcap=softcap)
+        assert np.isfinite(got).all()
+        want = naive_paged_attention(*scen, on, window=window,
+                                     softcap=softcap, block_size=LANE_BS)
+        np.testing.assert_allclose(got[on], want[on], rtol=1e-5, atol=1e-5)
+        # the oracle reads every column (masked), so it gets the pools
+        # with finite garbage where the kernel's had NaN
+        clean = build_lane_scenario(10, self.LENGTHS, self.COLS,
+                                    window=window, poison=False)
+        oracle = np.asarray(ref.paged_attention(
+            *map(jnp.asarray, clean), jnp.asarray(on), block_size=LANE_BS,
+            window=window, softcap=softcap))
+        np.testing.assert_allclose(got[on], oracle[on], rtol=1e-5, atol=1e-6)
+
+    def test_reads_no_block_past_the_live_ones(self):
+        """NaN or finite garbage in every block a slot must not read
+        moves no bit of any output: those blocks are never copied in."""
+        outs = [self._run(*build_lane_scenario(11, self.LENGTHS, self.COLS,
+                                               window=40, poison=p),
+                          blocks=4, window=40) for p in (True, False)]
+        np.testing.assert_array_equal(outs[0], outs[1])
+
+    @pytest.mark.parametrize("blocks", [1, 3, 16])
+    def test_any_block_count_per_step(self, blocks):
+        """One column per step, steps that straddle a slot's end, and one
+        step wider than every slot's context give the same answer."""
+        on = np.asarray(self.ACTIVE)
+        scen = build_lane_scenario(12, self.LENGTHS, self.COLS)
+        got = self._run(*scen, blocks=blocks)
+        assert np.isfinite(got).all()
+        want = naive_paged_attention(*scen, on,
+                                     block_size=LANE_BS)
+        np.testing.assert_allclose(got[on], want[on], rtol=1e-5, atol=1e-5)
+
+    def test_gqa(self):
+        """24 query heads on 12 kv heads: two query rows per kv head's
+        lanes, each with its own softmax."""
+        on = np.asarray(self.ACTIVE)
+        scen = build_lane_scenario(13, self.LENGTHS, self.COLS, g=2)
+        got = self._run(*scen, blocks=8, window=50)
+        assert got.shape == scen[0].shape and np.isfinite(got).all()
+        want = naive_paged_attention(*scen, on, window=50,
+                                     block_size=LANE_BS)
+        np.testing.assert_allclose(got[on], want[on], rtol=1e-5, atol=1e-5)
+
+
 def _gather_from_tables(tables, block_size):
     S, MB = tables.shape
     j = np.arange(MB * block_size)
@@ -296,6 +407,34 @@ class TestFusedEqualsReference:
                                               np.asarray(pool_r[key]))
 
 
+    @pytest.mark.parametrize("impl", ["reference", "fused"])
+    def test_pool_row_layout_moves_no_bit(self, impl):
+        """The same decode steps over a pool of (nkv*hd) rows and over
+        one of (nkv, hd) rows: logits and pool contents bitwise equal."""
+        cfg = tiny_config().replace(num_layers=2, d_model=32, d_ff=64,
+                                    num_heads=4, num_kv_heads=2, vocab=64)
+        params = transformer.init(cfg, jax.random.PRNGKey(2))
+        rng = np.random.default_rng(9)
+        S = 2
+        tables = jnp.asarray([[1, 2, TRASH], [3, 4, 5]], jnp.int32)
+        rows = transformer.init_paged_caches(cfg, 6, BS)
+        assert rows["k"].ndim == 3
+        L, T, D = rows["k"].shape
+        heads = {k: v.reshape(L, T, 2, D // 2) for k, v in rows.items()}
+        active = jnp.asarray([True, True])
+        for t in range(3):
+            token = jnp.asarray(rng.integers(0, cfg.vocab, (S, 1)), jnp.int32)
+            pos = jnp.asarray([2 + t, 5 + t], jnp.int32)
+            lr, rows = transformer.paged_serve_step(
+                cfg, params, rows, tables, token, pos, active, BS, impl=impl)
+            lh, heads = transformer.paged_serve_step(
+                cfg, params, heads, tables, token, pos, active, BS, impl=impl)
+            np.testing.assert_array_equal(np.asarray(lr), np.asarray(lh))
+        for key in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(rows[key]), np.asarray(heads[key]).reshape(L, T, D))
+
+
 class TestDispatchRouting:
     """ops.py routing contracts the serving paths rely on."""
 
@@ -305,12 +444,64 @@ class TestDispatchRouting:
         assert not kops.use_decode_kernel(128, 16)
         assert not kops.use_fused_mlp(4096, 11008)
 
-    def test_kernel_shape_gates(self):
-        # independent of backend: misaligned shapes always fall back
-        assert not kops.use_decode_kernel(64, 16)   # head_dim < lane width
+    def test_kernel_shape_gates(self, monkeypatch):
+        """On a TPU backend the gates read shapes only: head_dim, the kv
+        heads' lanes side by side, block_size."""
+        monkeypatch.setattr(kops, "_interpret", lambda: False)
+        # head_dim 64: the lane-dense kernel when 12 heads fill 768 lanes
+        assert kops.use_decode_lanes(64, 12, 16)
+        assert not kops.use_decode_kernel(64, 16)
+        # 64 and 192 lanes are not whole 128-lane rows; block_size 6 is
+        # not sublane-aligned: the oracle
+        assert not kops.use_decode_lanes(64, 1, 16)
+        assert not kops.use_decode_lanes(64, 3, 16)
+        assert not kops.use_decode_lanes(64, 12, 6)
+        # head_dim 128 keeps the per-head kernel
+        assert kops.use_decode_kernel(128, 16)
+        assert not kops.use_decode_lanes(128, 8, 16)
         assert not kops.use_decode_kernel(128, 6)   # block_size % 8 != 0
         assert not kops.use_fused_mlp(64, 11008)
         assert not kops.use_fused_mlp(4096, 128)
+
+    @pytest.mark.parametrize("hd,nkv,want", [(64, 12, "lanes"),
+                                             (64, 3, "oracle"),
+                                             (128, 8, "per_head")])
+    def test_ops_routes_by_shape_on_tpu(self, monkeypatch, hd, nkv, want):
+        """``ops.paged_decode_attn`` on a TPU backend: which path a shape
+        takes, with the pool in its own row layout, and the lane-dense
+        kernel given 128 context tokens a step."""
+        monkeypatch.setattr(kops, "_interpret", lambda: False)
+        took = {}
+
+        def spy(name):
+            def fn(q, *a, **kw):
+                took[name] = kw
+                return q
+            return fn
+
+        monkeypatch.setattr(pk, "paged_decode_attn_lanes", spy("lanes"))
+        monkeypatch.setattr(pk, "paged_decode_attn", spy("per_head"))
+        monkeypatch.setattr(ref, "paged_attention", spy("oracle"))
+        S, bs = 2, 16
+        pool = jnp.zeros((4 * bs,) + kops.pool_row_shape(nkv, hd))
+        kops.paged_decode_attn(jnp.zeros((S, nkv, hd)), pool, pool,
+                               jnp.zeros((S, 4), jnp.int32),
+                               jnp.zeros((S,), jnp.int32),
+                               jnp.ones((S,), bool), block_size=bs)
+        assert list(took) == [want]
+        if want == "lanes":
+            assert took[want]["blocks"] == 128 // bs
+
+    def test_pool_row_layout(self):
+        """Heads under 128 lanes sit side by side in one pool row; wider
+        heads keep a row per head (``ops.pool_row_shape``)."""
+        assert kops.pool_row_shape(12, 64) == (768,)
+        assert kops.pool_row_shape(3, 64) == (192,)
+        assert kops.pool_row_shape(8, 128) == (8, 128)
+        cfg = tiny_config().replace(num_layers=2, d_model=32, num_heads=4,
+                                    num_kv_heads=2)
+        pool = transformer.init_paged_caches(cfg, 5, BS)
+        assert pool["k"].shape == (2, 5 * BS, 2 * 8)
 
     def test_ops_paged_decode_attn_is_oracle_off_tpu(self):
         q, k, v, tables, pos = build_scenario(9, lengths=[4, 7])

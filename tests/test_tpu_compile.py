@@ -8,9 +8,12 @@ mode (tests/test_kernels.py, tests/test_paged_attention.py) checks the
 values; this file checks that the chip accepts the kernels at all.
 
 Widths are opt125m-proxy's (d_model 768, d_ff 3072, head_dim 64), plus
-the paged decode kernel at head_dim 128 with 8 kv heads (internlm2-20b's
-GQA shape; the kernel's ``head_dim >= 128`` gate keeps head_dim 64 on the
-reference gather).  Each compiled program must contain the kernel as a
+the per-head paged decode kernel at head_dim 128 with 8 kv heads
+(internlm2-20b's GQA shape; ``ops.use_decode_kernel`` takes it from
+head_dim 128 up).  Below that, ``ops.use_decode_lanes`` routes head_dim
+64 with 12 kv heads (768 lanes a pool row) to the lane-dense kernel,
+compiled here at the batch cell's serving shapes, alone and inside the
+whole decode step.  Each compiled program must contain the kernel as a
 ``tpu_custom_call``, and the kernels the benchmark's trace reduction
 finds by name (``bench/lib/trace.KERNELS``) must keep that name.
 """
@@ -121,3 +124,46 @@ def test_paged_decode_attn_gqa_hd128(one_chip):
         _sds(one_chip, (slots,), jnp.int32), _sds(one_chip, (slots,), jnp.bool_))
     assert "tpu_custom_call" in text
     assert _named(text, "paged_decode_attn")
+
+
+@pytest.mark.parametrize("nq", [12, 24])
+def test_paged_decode_attn_lanes_hd64(one_chip, nq):
+    """The batch cell's shapes: 64 slots, 12 kv heads of 64, blocks of
+    16, 96 table columns, 6,145 pool blocks of (16, 768) rows; and GQA
+    with two query heads per kv head."""
+    slots, nkv, hd, bs, blocks, cols = 64, 12, 64, 16, 6145, 96
+    pool = _sds(one_chip, (blocks * bs, nkv * hd), BF16)
+    text = _compile_text(
+        lambda q, k, v, t, p, a: paged_attention.paged_decode_attn_lanes(
+            q, k, v, t, p, a, block_size=bs),
+        _sds(one_chip, (slots, nq, hd), BF16), pool, pool,
+        _sds(one_chip, (slots, cols), jnp.int32),
+        _sds(one_chip, (slots,), jnp.int32), _sds(one_chip, (slots,), jnp.bool_))
+    assert "tpu_custom_call" in text
+    assert _named(text, "paged_decode_attn")
+
+
+def test_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
+    """opt125m-proxy's whole decode step, routed as on a TPU: the pool's
+    (nkv*hd) rows reach the kernel as they are stored, so no op of the
+    step holds a per-head (…, 12, 64) view of the pool."""
+    from repro.configs import opt125m_proxy
+    from repro.kernels import ops
+    from repro.models import transformer
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = opt125m_proxy.config().replace(tie_embeddings=True)
+    slots, cols, bs = 64, 96, 16
+    place = lambda t: jax.tree.map(                      # noqa: E731
+        lambda x: _sds(one_chip, x.shape, x.dtype), t)
+    params = place(jax.eval_shape(
+        lambda: transformer.init(cfg, jax.random.PRNGKey(0))))
+    pool = place(jax.eval_shape(
+        lambda: transformer.init_paged_caches(cfg, 6145, bs)))
+    text = _compile_text(
+        lambda p, c, t, tok, pos, a: transformer.paged_serve_step(
+            cfg, p, c, t, tok, pos, a, bs, impl="fused"),
+        params, pool, _sds(one_chip, (slots, cols), jnp.int32),
+        _sds(one_chip, (slots, 1), jnp.int32),
+        _sds(one_chip, (slots,), jnp.int32), _sds(one_chip, (slots,), jnp.bool_))
+    assert _named(text, "paged_decode_attn")
+    assert not re.search(r"bf16\[(?:\d+,)?98320,12,64\]", text)
