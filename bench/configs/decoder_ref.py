@@ -69,6 +69,50 @@ def dims(cfg: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
+def layout(dm: Dict[str, Any], bias: bool) -> Dict[str, tuple]:
+    """The seeded tree this reference reads: leaf path -> (shape, role),
+    the roles as ``bench/lib/weights`` gives them; ``bias`` adds q/k/v
+    biases."""
+    L, d, f, V = dm["layers"], dm["d"], dm["d_ff"], dm["vocab"]
+    q, kv = dm["heads"] * dm["head_dim"], dm["kv_heads"] * dm["head_dim"]
+    out = {"embed": ((V, d), "embed")}
+    if not dm["tie"]:
+        out["head"] = ((d, V), "dense_matrix")
+    norms = {"scale": "scale", "bias": "vector"}
+    for n in ("scale", "bias") if dm["norm"] == "layernorm" else ("scale",):
+        out[f"layers/ln1/{n}"] = out[f"layers/ln2/{n}"] = ((L, d), norms[n])
+        out[f"final_norm/{n}"] = ((d,), norms[n])
+    out.update({"layers/attn/wq": ((L, d, q), "matrix"),
+                "layers/attn/wk": ((L, d, kv), "matrix"),
+                "layers/attn/wv": ((L, d, kv), "matrix"),
+                "layers/attn/wo": ((L, q, d), "matrix")})
+    if bias:
+        out.update({"layers/attn/bq": ((L, q), "vector"),
+                    "layers/attn/bk": ((L, kv), "vector"),
+                    "layers/attn/bv": ((L, kv), "vector")})
+    if dm["act"] == "silu":
+        out.update({"layers/mlp/gate": ((L, d, f), "matrix"),
+                    "layers/mlp/up": ((L, d, f), "matrix"),
+                    "layers/mlp/down": ((L, f, d), "matrix")})
+    else:
+        out.update({"layers/mlp/fc1": ((L, d, f), "matrix"),
+                    "layers/mlp/b1": ((L, f), "vector"),
+                    "layers/mlp/fc2": ((L, f, d), "matrix"),
+                    "layers/mlp/b2": ((L, d), "vector")})
+    return out
+
+
+def program_sizes(dm: Dict[str, Any], bias: bool) -> Dict[str, Any]:
+    """The program's config attributes (dotted) that must equal these
+    sizes."""
+    return {"num_layers": dm["layers"], "d_model": dm["d"],
+            "num_heads": dm["heads"], "num_kv_heads": dm["kv_heads"],
+            "resolved_head_dim": dm["head_dim"], "d_ff": dm["d_ff"],
+            "vocab": dm["vocab"], "norm": dm["norm"], "act": dm["act"],
+            "rope_theta": dm["theta"], "tie_embeddings": dm["tie"],
+            "qkv_bias": bool(bias)}
+
+
 def fp8(a: jnp.ndarray, axis: int) -> jnp.ndarray:
     """Round to float8 e4m3 with one absmax scale per slice along ``axis``
     (the reduced axis of the matmul), back to float32."""

@@ -5,13 +5,21 @@ traffic mix; the harness reads ``bench/configs/<config>.json``,
 ``bench/traffic/<traffic>.json`` and ``bench/workloads/<name>.json``,
 and a per-layer metric ``<metric>`` is read by
 ``bench/metrics/<metric>.py``.  Adding any of them adds files only.
+
+Everything about a configuration's layer layout lives in its plain
+reference (``"reference"`` in the file): ``dims`` gives the sizes, and
+may give the layer kinds the work counts sum over (``lib/work``);
+``layout`` the seeded tree's leaves (``lib/weights``); ``program_sizes``
+the program's config attributes to check (``build_model``).
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib
 import importlib.util
 import json
 import os
+import re
 import sys
 from typing import Any, Dict, List, Tuple
 
@@ -52,36 +60,70 @@ def load_module(path: str, name: str):
 
 
 def reference(conf: Dict[str, Any]):
-    """The configuration's plain reference, beside it in ``configs/``."""
-    return load_module(os.path.join(BENCH, "configs",
-                                    conf["reference"] + ".py"),
-                       "bench_ref_" + conf["reference"])
+    """The configuration's plain reference: ``"reference": "<name>"`` is
+    ``configs/<name>.py``, beside the file; a name with a ``/`` is a
+    path under ``bench/`` without the ``.py``."""
+    name = conf["reference"]
+    path = os.path.join(BENCH, *name.split("/")) if "/" in name else \
+        os.path.join(BENCH, "configs", name)
+    return load_module(path + ".py",
+                       "bench_ref_" + re.sub(r"\W", "_", name))
 
 
 def ref_dims(conf: Dict[str, Any]) -> Dict[str, Any]:
-    return reference(conf).dims(conf)
+    """The reference's sizes, with ``"leaves"``: (path, shape, role,
+    dtype) of every leaf of its layout, which the work counts read."""
+    from lib import weights
+    ref = reference(conf)
+    dm = ref.dims(conf)
+    return dict(dm, leaves=weights.leaves(
+        dm, conf.get("enable_bias", False), ref.layout))
+
+
+def program_value(cfg: Any, dotted: str) -> Any:
+    """``cfg.<dotted attribute>``; a method is called.  An attribute the
+    config lacks is an error."""
+    value = cfg
+    for part in dotted.split("."):
+        if not hasattr(value, part):
+            raise ValueError(f"the program's config has no {dotted!r}")
+        value = getattr(value, part)
+    return value() if callable(value) else value
 
 
 def build_model(conf: Dict[str, Any]):
     """The system's ModelDef for a configuration file, built through the
-    registry, and checked against the file's sizes."""
+    registry, and checked against the file's sizes.
+
+    ``model_overrides`` replaces fields of the program's config; an
+    object replaces fields of the nested config under its key
+    (``{"moe": {"num_experts": 8}}``).  The sizes checked are the
+    reference's ``program_sizes(dm, bias)`` (dotted attribute -> value)
+    and the dtype."""
     from repro.models.registry import model_def
     mod = importlib.import_module(
         "repro.configs." + conf["arch"].replace("-", "_").replace(".", "_"))
-    cfg = mod.config().replace(**conf.get("model_overrides", {}))
-    dm = ref_dims(conf)
-    got = {"layers": cfg.num_layers, "d": cfg.d_model, "heads": cfg.num_heads,
-           "kv_heads": cfg.num_kv_heads, "head_dim": cfg.resolved_head_dim(),
-           "d_ff": cfg.d_ff, "vocab": cfg.vocab, "norm": cfg.norm,
-           "act": cfg.act, "theta": float(cfg.rope_theta),
-           "tie": cfg.tie_embeddings}
-    bad = {k: (v, dm[k]) for k, v in got.items() if v != dm[k]}
+    cfg = mod.config()
+    over = {}
+    for key, value in conf.get("model_overrides", {}).items():
+        if isinstance(value, dict):
+            nested = getattr(cfg, key, None)
+            if not dataclasses.is_dataclass(nested):
+                raise ValueError(f"{conf['name']}: model_overrides.{key} "
+                                 f"names no nested config ({nested!r})")
+            value = dataclasses.replace(nested, **value)
+        over[key] = value
+    cfg = cfg.replace(**over)
+    ref = reference(conf)
+    dm = ref.dims(conf)
+    want = ref.program_sizes(dm, conf.get("enable_bias", False))
+    bad = {k: (got, v) for k, v in want.items()
+           if (got := program_value(cfg, k)) != v}
     dtype = conf.get("assumed", {}).get("torch_dtype", conf["torch_dtype"])
-    if bad or cfg.param_dtype != dtype or cfg.qkv_bias != bool(
-            conf.get("enable_bias", False)):
+    if bad or cfg.param_dtype != dtype:
         raise ValueError(f"{conf['name']}: the program's config departs from "
-                         f"the file: {bad}, dtype {cfg.param_dtype}, "
-                         f"qkv_bias {cfg.qkv_bias}")
+                         f"the file: {bad} (program, file), dtype "
+                         f"{cfg.param_dtype}")
     return model_def(cfg)
 
 

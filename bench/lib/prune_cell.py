@@ -45,7 +45,7 @@ def setup(cell, conf, job, seed) -> Dict[str, Any]:
     from repro import api, obs
     model = harness.build_model(conf)
     dm = harness.ref_dims(conf)
-    params = weights.make(dm, conf.get("enable_bias", False), seed)
+    params = weights.for_config(conf, seed)
     calib = calibration(job, dm["vocab"], seed)
     rec = recipe(conf, job)
     obs.enable()                     # as the prune launcher runs a prune
@@ -83,8 +83,7 @@ def check(conf, cell, job, seed, answer: Dict[str, Any]) -> Dict[str, float]:
     the units ``cell["check"]["units"]`` drawn from the seed."""
     ref = harness.reference(conf)
     dm = ref.dims(conf)
-    bias = conf.get("enable_bias", False)
-    dense = weights.make(dm, bias, seed)
+    dense = weights.for_config(conf, seed)
     calib = calibration(job, dm["vocab"], seed)
     pruned = answer["pruned"]
     out = {"nm_violations": float(prune_ref.nm_violations(pruned, dm["act"]))}
@@ -182,7 +181,7 @@ def control_answer(conf, job, seed) -> Dict[str, Any]:
     precision lower (bf16 relay, statistics and solves), every unit."""
     ref = harness.reference(conf)
     dm = ref.dims(conf)
-    dense = weights.make(dm, conf.get("enable_bias", False), seed)
+    dense = weights.for_config(conf, seed)
     calib = calibration(job, dm["vocab"], seed)
     T = calib[0]["tokens"].shape[1]
     pos = jnp.arange(T, dtype=jnp.int32)

@@ -43,8 +43,8 @@ def setup(cell: Dict[str, Any], conf: Dict[str, Any], seed: int
     from repro.serve.engine import prepare_serving_params
     model = harness.build_model(conf)
     dm = harness.ref_dims(conf)
-    params = weights.make(dm, conf.get("enable_bias", False), seed,
-                          sparse24=cell["weights"] == "2:4-magnitude")
+    params = weights.for_config(conf, seed,
+                                sparse24=cell["weights"] == "2:4-magnitude")
     sv = cell["serving"]
     bcfg = BatchConfig(slots=sv["slots"], block_size=sv["block_size"],
                        max_blocks_per_request=sv["max_blocks_per_request"],
@@ -203,8 +203,8 @@ def logit_gaps(conf: Dict[str, Any], cell: Dict[str, Any], seed: int,
     control puts first, not the served one."""
     ref = harness.reference(conf)
     dm = ref.dims(conf)
-    params = weights.make(dm, conf.get("enable_bias", False), seed,
-                          sparse24=cell["weights"] == "2:4-magnitude")
+    params = weights.for_config(conf, seed,
+                                sparse24=cell["weights"] == "2:4-magnitude")
     fns = ref.make_forward(dm)
     out = []
     for prompt, toks in picked:

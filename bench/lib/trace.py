@@ -13,10 +13,22 @@ reads the ``.xplane.pb`` with nothing but JAX and returns:
   ``jit_<fn>`` without the prefix): device time and executions;
 * per program, the device time of each kernel inside it;
 * ``breakdown``: the ten device operations that took most time, and
-  idle time by the harness span that was open in the middle of each gap.
+  idle time by the harness span that was open in the middle of each gap;
+* the program's own spans (``repro.obs`` writes each ``serve.*`` and
+  ``prune.*`` span into a running trace as a ``TraceAnnotation``):
+  ``program_spans``, span name -> ``{"seconds", "count"}`` clipped to
+  the stretch, and ``idle_by_program_span``, idle seconds on the device
+  by the innermost program span open at each gap's middle
+  (``PROGRAM_OUTSIDE`` where none is), averaged over the chips as the
+  idle gaps are.  A program that writes no spans leaves both empty.
+
+A kernel is found by its HLO instruction's name.  ``KERNELS`` are the
+ones every reduction knows; a reader under ``bench/metrics/`` adds its
+own by a module constant ``KERNELS = ("<name>", ...)``.
 """
 from __future__ import annotations
 
+import ast
 import bisect
 import collections
 import glob
@@ -27,8 +39,13 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 #: kernels of the system under test: a Pallas call's HLO instruction
 #: takes the name of the jitted function that wraps it (``%spmm24.41``)
-KERNELS = {k: re.compile(rf"^%?{k}(?:\.\d+)? = ") for k in
-           ("spmm24", "fused_mlp24", "paged_decode_attn", "flash_attention")}
+KERNELS = ("spmm24", "fused_mlp24", "paged_decode_attn", "flash_attention")
+#: the per-layer readers, each of which may declare kernels of its own
+METRICS_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "metrics")
+#: spans of the program (``repro.obs``), by prefix
+PROGRAM_PREFIXES = ("serve.", "prune.")
+PROGRAM_OUTSIDE = "outside the program's spans"
 
 MODULE_RE = re.compile(r"^(?:jit_)?(?P<name>.*?)(?:\(\d+\))?$")
 #: an op event's name is its HLO text; the name proper comes first
@@ -126,11 +143,37 @@ def _events(line) -> List[Tuple[str, float, float]]:
             for ev in line.events]
 
 
-def _short(op: str) -> str:
+def reader_kernels() -> Tuple[str, ...]:
+    """Kernel names the readers in ``METRICS_DIR`` declare as
+    ``KERNELS = (...)``, read from the files, not run."""
+    names: List[str] = []
+    for path in sorted(glob.glob(os.path.join(METRICS_DIR, "*.py"))):
+        with open(path, encoding="utf-8") as f:
+            body = ast.parse(f.read(), path).body
+        for node in body:
+            if isinstance(node, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id == "KERNELS"
+                    for t in node.targets):
+                names += ast.literal_eval(node.value)
+    return tuple(names)
+
+
+def kernel_patterns() -> Dict[str, "re.Pattern"]:
+    """``KERNELS`` and the readers' kernels, each to the pattern of its
+    op's HLO text."""
+    return {k: re.compile(rf"^%?{re.escape(k)}(?:\.\d+)? = ")
+            for k in dict.fromkeys(KERNELS + reader_kernels())}
+
+
+def _kernel(op: str, kernels) -> Optional[str]:
+    return next((k for k, rx in kernels.items() if rx.search(op)), None)
+
+
+def _short(op: str, kernels) -> str:
     """An op's HLO name, with the kernel it calls where it calls one."""
     m = OP_RE.match(op)
     name = m.group("name") if m else op[:64]
-    kern = next((k for k, rx in KERNELS.items() if rx.search(op)), None)
+    kern = _kernel(op, kernels)
     return f"{name} ({kern})" if kern else name
 
 
@@ -144,15 +187,38 @@ def _label(spans, starts, t: float, look: int = 64) -> str:
     return min(open_, key=lambda h: h[2] - h[1])[0]
 
 
+def _innermost(spans: List[Tuple[str, float, float]],
+               points: List[float]) -> List[Optional[str]]:
+    """For each of ``points`` (ascending), the shortest span with
+    start <= t <= end, or None."""
+    spans = sorted(spans, key=lambda h: h[1])
+    out: List[Optional[str]] = []
+    active: List[Tuple[str, float, float]] = []
+    i = 0
+    for t in points:
+        while i < len(spans) and spans[i][1] <= t:
+            active.append(spans[i])
+            i += 1
+        active = [h for h in active if h[2] >= t]
+        out.append(min(active, key=lambda h: h[2] - h[1])[0]
+                   if active else None)
+    return out
+
+
 def reduce_planes(planes, chips: int = 1) -> Optional[Dict[str, Any]]:
     """The numbers above, from the planes of one ``ProfileData``."""
     planes = list(planes)
+    kernels = kernel_patterns()
     host_spans: List[Tuple[str, float, float]] = []
+    own_spans: List[Tuple[str, float, float]] = []
     for p in planes:
         if p.name.startswith("/host:"):
             for line in p.lines:
-                host_spans += [e for e in _events(line)
-                               if e[0].startswith("bench.")]
+                for e in _events(line):
+                    if e[0].startswith("bench."):
+                        host_spans.append(e)
+                    elif e[0].startswith(PROGRAM_PREFIXES):
+                        own_spans.append(e)
     win = [e for e in host_spans if e[0] == "bench.window"]
     devs = sorted((p for p in planes if p.name.startswith("/device:TPU:")
                    and re.fullmatch(r"/device:TPU:\d+", p.name)),
@@ -163,11 +229,17 @@ def reduce_planes(planes, chips: int = 1) -> Optional[Dict[str, Any]]:
     spans = sorted((h for h in host_spans if h[0] != "bench.window"),
                    key=lambda h: h[1])
     span_starts = [h[1] for h in spans]
+    own = [(n, max(s, w0), min(e, w1)) for n, s, e in own_spans
+           if e > w0 and s < w1]
+    own_s, own_n = collections.Counter(), collections.Counter()
+    for n, s, e in own:
+        own_s[n] += (e - s) * 1e-9
+        own_n[n] += 1
     busy_total, ops_t = 0.0, collections.Counter()
     prog_t, prog_n = collections.Counter(), collections.Counter()
     kern_t: Dict[str, collections.Counter] = collections.defaultdict(
         collections.Counter)
-    gaps = collections.Counter()
+    gaps, own_idle = collections.Counter(), collections.Counter()
     for p in devs:
         lines = {ln.name: _events(ln) for ln in p.lines}
         ops = [(n, max(s, w0), min(e, w1)) for n, s, e in
@@ -179,7 +251,7 @@ def reduce_planes(planes, chips: int = 1) -> Optional[Dict[str, Any]]:
         busy_total += sum(e - s for s, e in busy)
         for n, s, e in ops:
             if not CONTAINER_RE.search(n):
-                ops_t[_short(n)] += e - s
+                ops_t[_short(n, kernels)] += e - s
         for n, s, e in mods:
             prog_t[n] += e - s
             prog_n[n] += 1
@@ -187,18 +259,21 @@ def reduce_planes(planes, chips: int = 1) -> Optional[Dict[str, Any]]:
         mods.sort(key=lambda m: m[1])
         starts = [m[1] for m in mods]
         for n, s, e in ops:
-            kern = next((k for k, rx in KERNELS.items() if rx.search(n)),
-                        None)
+            kern = _kernel(n, kernels)
             if kern is None:
                 continue
             i = bisect.bisect_right(starts, s) - 1
             owner = mods[i][0] if i >= 0 and mods[i][2] >= e else "?"
             kern_t[owner][kern] += e - s
-        # idle gaps, by the innermost harness span open at their middle
+        # idle gaps, by the innermost harness span and program span open
+        # at their middle
         edges = [w0] + [x for iv in busy for x in iv] + [w1]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b > a:
-                gaps[_label(spans, span_starts, 0.5 * (a + b))] += b - a
+        idle = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+        for a, b in idle:
+            gaps[_label(spans, span_starts, 0.5 * (a + b))] += b - a
+        names = _innermost(own, [0.5 * (a + b) for a, b in idle])
+        for (a, b), n in zip(idle, names):
+            own_idle[n or PROGRAM_OUTSIDE] += b - a
     k = len(devs)
     ns = 1e-9 / k
     prog_s = {n: t * ns for n, t in prog_t.items()}
@@ -223,6 +298,10 @@ def reduce_planes(planes, chips: int = 1) -> Optional[Dict[str, Any]]:
                     for p, c in kern_t.items()},
         "program_time": program_time, "program_count": program_count,
         "kernel_time": kernel_time,
+        "program_spans": {n: {"seconds": own_s[n], "count": own_n[n]}
+                          for n in sorted(own_s)},
+        "idle_by_program_span": {n: t * ns
+                                 for n, t in own_idle.most_common()},
         "breakdown": {
             "device_ops": [[n, t * ns] for n, t in ops_t.most_common(10)],
             "idle_gaps": [[n, t * ns] for n, t in gaps.most_common(10)]},

@@ -1,7 +1,9 @@
 """Kernel paged_decode_attn inside the decode step: roofline share, %.
 
-Least time to read the K/V of the active contexts (and their q and
-output rows) at the chip's peaks, over the kernel's device time per step."""
+Least time to read the K/V of the active contexts (a windowed layer's
+capped at its window) and their q and output rows at the chip's peaks,
+over the kernel's device time per step.  Nothing where the run did not
+count what a windowed layer reads (``work.WINDOWED_CONTEXT``)."""
 from lib import work
 
 
@@ -11,7 +13,8 @@ def read(ctx):
     s = t["kernel_time"]("step", "paged_decode_attn")
     if not n or not s or not st["steps"]:
         return None
-    w = work.decode_attention(dm, st["active_slot_steps"] / st["steps"],
-                              st["context_tokens"] / st["steps"])
+    w = work.decode_attention(dm, **work.per_step(st))
+    if w is None:
+        return None
     least = max(w["flops"] / pk["bf16_flops"], w["bytes"] / pk["hbm_bytes_per_s"])
     return 100.0 * least / (s / n)

@@ -3,7 +3,9 @@
 The larger of FLOPs / peak FLOP/s and bytes / peak bytes/s, over the
 device time of one step; stderr says which of the two bounds it.
 Bytes are the weights as the cell stores them plus the K/V of the
-active contexts, averaged over the window's steps."""
+active contexts (a windowed layer's capped at its window), averaged
+over the window's steps.  Nothing where the run did not count what a
+windowed layer reads (``work.WINDOWED_CONTEXT``)."""
 from lib import harness, work
 
 
@@ -14,9 +16,10 @@ def read(ctx):
     if not n or not st["steps"]:
         return None
     step_s = t["program_time"]("step") / n
-    w = work.decode_step(dm, st["active_slot_steps"] / st["steps"],
-                         st["context_tokens"] / st["steps"],
-                         run["sparse"].get("mode") == "packed")
+    w = work.decode_step(dm, packed=run["sparse"].get("mode") == "packed",
+                         **work.per_step(st))
+    if w is None:
+        return None
     tf, tb = w["flops"] / pk["bf16_flops"], w["bytes"] / pk["hbm_bytes_per_s"]
     harness.stderr(f"bench: decode step bound by "
                    f"{'bytes' if tb >= tf else 'FLOPs'}: {w}")

@@ -2,12 +2,15 @@
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
-They cover the trace reduction on a trace recorded on the chip, the
-work counts against hand-worked shapes, the traffic generator, the TTFT
-reading under a slow prefill, the controls (the reference one precision
-lower must read worse than the program), and the faults that a run must
-report as not correct.  The cells run here at small sizes, with the
-harness's look for a chip skipped.
+They cover the trace reduction and the readers on traces recorded on
+the chip, the work counts against hand-worked shapes, the pins that
+hold the dense default of the layout rules to what the harness did
+before, a configuration that brings its own layout (an expert fixture
+in ``testdata``), the traffic generator, the TTFT reading under a slow
+prefill, the controls (the reference one precision lower must read
+worse than the program), and the faults that a run must report as not
+correct.  The cells run here at small sizes, with the harness's look
+for a chip skipped.
 """
 from __future__ import annotations
 
@@ -22,7 +25,7 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path[:0] = [BENCH, os.path.join(os.path.dirname(BENCH), "src")]
 
-from lib import harness, traffic, work  # noqa: E402
+from lib import harness, traffic, weights, work  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -166,29 +169,453 @@ def test_prune_flops_by_hand():
 
 
 # ---------------------------------------------------------------------------
+# pins: the dense default of the layout rules gives what the harness gave
+# before configurations could bring their own layout
+# ---------------------------------------------------------------------------
+CONFIG_FILES = ["opt-125m", "internlm2-20b.d8"]
+
+
+def _pinned_shapes(dm, bias):
+    """A copy of ``weights.shapes`` as it was, with its role rule."""
+    L, d, f, V = dm["layers"], dm["d"], dm["d_ff"], dm["vocab"]
+    q, kv = dm["heads"] * dm["head_dim"], dm["kv_heads"] * dm["head_dim"]
+    out = {"embed": (V, d)} if dm["tie"] else {"embed": (V, d), "head": (d, V)}
+    norms = ("scale", "bias") if dm["norm"] == "layernorm" else ("scale",)
+    for n in norms:
+        out[f"layers/ln1/{n}"] = out[f"layers/ln2/{n}"] = (L, d)
+        out[f"final_norm/{n}"] = (d,)
+    out.update({"layers/attn/wq": (L, d, q), "layers/attn/wk": (L, d, kv),
+                "layers/attn/wv": (L, d, kv), "layers/attn/wo": (L, q, d)})
+    if bias:
+        out.update({"layers/attn/bq": (L, q), "layers/attn/bk": (L, kv),
+                    "layers/attn/bv": (L, kv)})
+    if dm["act"] == "silu":
+        out.update({"layers/mlp/gate": (L, d, f), "layers/mlp/up": (L, d, f),
+                    "layers/mlp/down": (L, f, d)})
+    else:
+        out.update({"layers/mlp/fc1": (L, d, f), "layers/mlp/b1": (L, f),
+                    "layers/mlp/fc2": (L, f, d), "layers/mlp/b2": (L, d)})
+    return out
+
+
+def _pinned_role(path, shp):
+    if path.endswith("scale"):
+        return "scale"
+    if path == "embed":
+        return "embed"
+    if path == "head":                  # unit gain, never masked
+        return "dense_matrix"
+    return "matrix" if len(shp) == 3 else "vector"
+
+
+def _pinned_make(key, leaves, sparse24, tie):
+    """A copy of ``weights._make`` as it was, on (path, shape) leaves."""
+    import jax
+    import jax.numpy as jnp
+    from lib import weights
+
+    def make(key):
+        out = {}
+        for i, (path, shp) in enumerate(leaves):
+            k = jax.random.fold_in(key, i)
+            z = jax.random.normal(k, shp, jnp.bfloat16)
+            if path.endswith("scale"):
+                w = 1.0 + 0.1 * z
+            elif path == "embed":
+                w = z * (shp[-1] ** -0.5) if tie else z
+            elif len(shp) == 3 or path == "head":
+                w = z * (shp[-2] ** -0.5)
+                if sparse24 and len(shp) == 3:
+                    w = weights.mask24(w)
+            else:
+                w = 0.1 * z
+            out[path] = w.astype(jnp.bfloat16)
+        return out
+    return jax.jit(make)(key)
+
+
+def _config_dims(name):
+    conf = harness.read_json(BENCH, "configs", f"{name}.json")
+    return conf, harness.ref_dims(conf)
+
+
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_pinned_leaves_of_config_files(name):
+    """The dense reference's layout gives the leaves, roles and dtypes
+    of the old dense rule, in the same order, and the harness hands
+    them to the work counts."""
+    from lib import weights
+    conf, dm = _config_dims(name)
+    bias = conf.get("enable_bias", False)
+    ref = harness.reference(conf)
+    want = tuple((p, s, _pinned_role(p, s), "bfloat16")
+                 for p, s in sorted(_pinned_shapes(dm, bias).items()))
+    assert weights.leaves(dm, bias, ref.layout) == want
+    assert dm["leaves"] == want
+
+
+@pytest.mark.parametrize("sparse24", [False, True], ids=["dense", "2:4"])
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_pinned_weights_bit_identical(name, sparse24):
+    """At a reduced size of each file's dims, every seeded array is bit
+    for bit what the harness seeded before layouts."""
+    import jax
+    from lib import weights
+    conf, dm = _config_dims(name)
+    small = dict(dm, layers=2, d=64, heads=4, kv_heads=2 if dm["kv_heads"]
+                 != dm["heads"] else 4, head_dim=16, d_ff=128, vocab=256)
+    bias = conf.get("enable_bias", False)
+    seed = 2 ** 33 + 17
+    layout = harness.reference(conf).layout
+    got = jax.tree_util.tree_flatten_with_path(
+        weights.make(small, bias, seed, sparse24, layout))[0]
+    want = _pinned_make(weights.seed_key(seed),
+                        tuple(sorted(_pinned_shapes(small, bias).items())),
+                        sparse24, small["tie"])
+    assert len(got) == len(want)
+    for path, arr in got:
+        key = "/".join(p.key for p in path)
+        assert arr.dtype == want[key].dtype
+        np.testing.assert_array_equal(np.asarray(arr).view(np.uint16),
+                                      np.asarray(want[key]).view(np.uint16))
+
+
+def _pinned_work(dm):
+    """Copies of the work counts as they were, before layer kinds."""
+    d, f = dm["d"], dm["d_ff"]
+    q, kv = dm["heads"] * dm["head_dim"], dm["kv_heads"] * dm["head_dim"]
+    mats = {"attn/wq": (d, q), "attn/wk": (d, kv), "attn/wv": (d, kv),
+            "attn/wo": (q, d)}
+    if dm["act"] == "silu":
+        mats.update({"mlp/gate": (d, f), "mlp/up": (d, f), "mlp/down": (f, d)})
+    else:
+        mats.update({"mlp/fc1": (d, f), "mlp/fc2": (f, d)})
+    lw = sum(i * o for i, o in mats.values())
+    L, hd = dm["layers"], dm["head_dim"]
+    kvb = 2 * L * dm["kv_heads"] * hd * 2
+
+    def decode_step(tokens, context, packed):
+        head = d * dm["vocab"]
+        flops = 2.0 * tokens * (L * lw * (0.5 if packed else 1.0) + head)
+        flops += 4.0 * L * dm["heads"] * hd * context
+        wbytes = L * lw * (1.25 if packed else 2) + head * 2
+        return {"flops": flops, "bytes": wbytes + context * kvb}
+
+    def kernel(prefix, rows):
+        flops, nbytes = 0.0, 0.0
+        for key, (i, o) in mats.items():
+            if key.startswith(prefix):
+                flops += 2.0 * rows * i * o * 0.5
+                nbytes += i * o * 1.25 + (rows * (i + o) * 2
+                                          if prefix == "attn/" else 0)
+        if prefix == "mlp/":
+            nbytes += 2 * rows * d * 2
+        return {"flops": L * flops, "bytes": L * nbytes}
+
+    def decode_attention(tokens, context):
+        return {"flops": 4.0 * L * dm["heads"] * hd * context,
+                "bytes": context * kvb + 2.0 * L * tokens * dm["heads"] * hd
+                * 2}
+
+    def unit_forward(seqs, seq_len):
+        return 2.0 * (seqs * seq_len) * lw \
+            + 2.0 * seqs * seq_len * seq_len * dm["heads"] * hd
+
+    def prune(seqs, seq_len, reports):
+        tokens = seqs * seq_len
+        flops = (2 + 4) * unit_forward(seqs, seq_len)
+        for i, o in mats.values():
+            flops += 3 * 2.0 * tokens * i * i + 2.0 * tokens * i * o
+        flops *= L
+        for r in reports:
+            m, n = r["shape"]
+            flops += 2.0 * m * n * n * (r["fista_iters"]
+                                        + 2 * r["outer_iters"] + 1) \
+                + 64 * 2.0 * n * n
+        return flops
+    return {"layer_weights": lw,
+            "decode_step": decode_step, "spmm24": lambda r: kernel("attn/", r),
+            "fused_mlp24": lambda r: kernel("mlp/", r),
+            "decode_attention": decode_attention, "unit_forward": unit_forward,
+            "prune": prune}
+
+
+WORK_FNS = ["decode_step", "spmm24", "fused_mlp24", "decode_attention",
+            "unit_forward", "prune"]
+
+
+def _work_cases():
+    reps = [{"shape": (768, 3072), "fista_iters": 97, "outer_iters": 5},
+            {"shape": (6144, 1024), "fista_iters": 13, "outer_iters": 2}]
+    return {
+        "decode_step": [(t, c, p) for t, c, p in
+                        [(63.1875, 22615.25, True), (20, 20_000, False),
+                         (1, 17, True)]],
+        "spmm24": [(r,) for r in (1, 32, 64)],
+        "fused_mlp24": [(r,) for r in (1, 32, 64)],
+        "decode_attention": [(63.1875, 22615.25), (10, 1000)],
+        "unit_forward": [(128, 2048), (16, 128)],
+        "prune": [(s, t, reps) for s, t in [(128, 2048), (16, 128)]]}
+
+
+@pytest.mark.parametrize("fn", WORK_FNS + ["sizes"])
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_pinned_work_counts(name, fn):
+    """Every count for both files' dims equals the count before layer
+    kinds, to the last bit (``==``, no tolerance)."""
+    _, dm = _config_dims(name)
+    old = _pinned_work(dm)
+    if fn == "sizes":
+        assert work.layer_weights(dm) == old["layer_weights"]
+        return
+    for args in _work_cases()[fn]:
+        got = getattr(work, fn)(dm, *args)
+        want = old[fn](*args)
+        if isinstance(want, dict):
+            got = {k: got[k] for k in want}
+        assert got == want, (fn, args)
+
+
+@pytest.mark.parametrize("fn", WORK_FNS)
+@pytest.mark.parametrize("name", CONFIG_FILES)
+def test_declared_dense_kinds_equal_the_default(name, fn):
+    """The dense decoder declared as one layer kind over its layout's
+    leaves (in, out, stored, role and dtype read from them) counts what
+    the default of its sizes counts, to the last bit."""
+    _, dm = _config_dims(name)
+    hd = dm["head_dim"]
+    declared = dict(dm, layer_kinds=[{
+        "count": dm["layers"],
+        "operators": {p: 1 for p, _, r, _ in dm["leaves"] if r == "matrix"},
+        "attention": {"heads": dm["heads"], "head_dim": hd,
+                      "kv_elems_per_token": 2 * dm["kv_heads"] * hd,
+                      "window": None}}])
+    for args in _work_cases()[fn]:
+        assert getattr(work, fn)(declared, *args) == \
+            getattr(work, fn)(dm, *args), (fn, args)
+
+
+# ---------------------------------------------------------------------------
+# a configuration that brings its own layout: a test fixture of experts
+# ---------------------------------------------------------------------------
+def _fixture():
+    return harness.read_json(BENCH, "testdata", "qwen2-moe-smoke.json")
+
+
+def test_layout_fixture_builds_and_seeds_the_programs_tree():
+    """The nested ``moe`` override reaches the program's config, and the
+    seeded tree has the structure, shapes and dtypes of the program's
+    own init: experts stacked (layers, experts, in, out), 2:4 along
+    their input axis; the router (float32) and the gate never masked."""
+    import jax
+    conf = _fixture()
+    model = harness.build_model(conf)
+    assert model.cfg.moe.num_experts == 8 and model.cfg.moe.top_k == 2
+    assert model.cfg.moe.shared_ff == 64 and model.cfg.moe.num_shared == 1
+    tree = weights.for_config(conf, 2 ** 31 + 3, sparse24=True)
+    want = jax.eval_shape(model.init, jax.random.key(0))
+    assert jax.tree.structure(tree) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(want)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+    moe = tree["layers"]["moe"]
+    w = np.asarray(moe["w_gate"], np.float32)             # (2, 8, 64, 32)
+    kept = (w.reshape(2, 8, 16, 4, 32) != 0).sum(axis=3)
+    assert kept.max() == 2
+    assert moe["router"].dtype == np.float32
+    assert np.all(np.asarray(moe["router"]) != 0)
+    assert np.all(np.asarray(moe["shared_gate"], np.float32) != 0)
+
+
+@pytest.mark.parametrize("change", [
+    {"moe": {"top_k": 3}}, {"num_layers": 3}, {"window": 16}],
+    ids=["nested size", "depth", "window"])
+def test_layout_fixture_program_sizes_are_checked(change):
+    """A program config that departs from the reference's
+    ``program_sizes``, in a nested config too, is refused."""
+    conf = _fixture()
+    over = dict(conf["model_overrides"])
+    for k, v in change.items():
+        over[k] = dict(over[k], **v) if isinstance(v, dict) else v
+    with pytest.raises(ValueError, match="departs from the file"):
+        harness.build_model(dict(conf, model_overrides=over))
+
+
+def test_program_value_refuses_what_the_config_lacks():
+    from repro.configs import qwen2_moe_a2_7b
+    cfg = qwen2_moe_a2_7b.config()
+    assert harness.program_value(cfg, "moe.top_k") == 4
+    assert harness.program_value(cfg, "resolved_head_dim") == 128
+    with pytest.raises(ValueError, match="no 'moe.no_such'"):
+        harness.program_value(cfg, "moe.no_such")
+    with pytest.raises(ValueError, match="names no nested config"):
+        harness.build_model(dict(_fixture(), model_overrides={
+            "ssm": {"state": 4}}))
+
+
+def test_layout_fixture_work_by_hand():
+    """A token passes top_k of the experts, the chip stores all of them;
+    the router, the shared expert and its gate run for every token.  A
+    packed cell packs the 2:4 matrices only: the float32 router and the
+    gate keep their bytes and FLOPs."""
+    dm = harness.ref_dims(_fixture())
+    d, E, k, fe, fs, L, V = 64, 8, 2, 32, 64, 2, 256
+    attn = 4 * d * d
+    routed = k * 3 * d * fe
+    per_token = attn + d * E + routed + 3 * d * fs + d
+    stored = attn + d * E + E * 3 * d * fe + 3 * d * fs + d
+    masked = attn + E * 3 * d * fe + 3 * d * fs        # role "matrix"
+    kv = 300 * L * 2 * 4 * 16 * 2                  # K and V, 4 heads of 16
+    w = work.decode_step(dm, tokens=5, context=300, packed=False)
+    assert w["flops"] == pytest.approx(2 * 5 * (L * per_token + d * V)
+                                       + 4 * L * 4 * 16 * 300)
+    assert w["bytes"] == pytest.approx(
+        L * ((stored - d * E) * 2 + d * E * 4) + d * V * 2 + kv)
+    p = work.decode_step(dm, tokens=5, context=300, packed=True)
+    assert w["bytes"] - p["bytes"] == pytest.approx(L * masked * 0.75)
+    assert w["flops"] - p["flops"] == pytest.approx(
+        2 * 5 * L * (attn + routed + 3 * d * fs) * 0.5)
+    assert work.layer_weights(dm) == stored
+    # no operator under attn/ or mlp/ besides attention: no fused MLP
+    assert work.fused_mlp24(dm, rows=8) == {"flops": 0.0, "bytes": 0.0}
+    assert work.spmm24(dm, rows=8)["flops"] == pytest.approx(
+        L * 8 * attn)
+    seqs, T = 4, 32
+    assert work.unit_forward(dm, seqs, T) == pytest.approx(
+        2 * seqs * T * per_token + 2 * seqs * T * T * 4 * 16)
+
+
+def test_layout_fixture_held_share_of_experts():
+    """On a chip that holds 2 of the 8 experts, the layout's expert axis
+    gives what one layer stores, and per_token what a token passes; an
+    operator that names no stacked matrix of the layout is refused."""
+    conf = _fixture()
+    ref = harness.reference(conf)
+    dm = dict(harness.ref_dims(conf), held=2)
+    dm["layer_kinds"] = [dict(dm["layer_kinds"][0], operators=dict(
+        dm["layer_kinds"][0]["operators"], **{
+            f"layers/moe/{p}": 2 * 2 / 8 for p in ("w_gate", "w_up",
+                                                   "w_down")}))]
+    from lib import weights
+    dm["leaves"] = weights.leaves(dm, False, ref.layout)
+    d, fe, fs, E = 64, 32, 64, 8
+    base = 4 * d * d + d * E + 3 * d * fs + d
+    assert work.layer_weights(dm) == base + 2 * 3 * d * fe
+    full = harness.ref_dims(conf)
+    a = work.decode_step(dm, 1, 10, False)
+    b = work.decode_step(full, 1, 10, False)
+    assert b["flops"] - a["flops"] == pytest.approx(
+        2 * 2 * (2 - 0.5) * 3 * d * fe)
+    for path, why in [("layers/moe/nope", "no leaf"),
+                      ("layers/attn/bq", "not a matrix"),
+                      ("head", "not a matrix")]:
+        bad = dict(full, layer_kinds=[dict(full["layer_kinds"][0],
+                                           operators={path: 1})])
+        with pytest.raises(ValueError, match=why):
+            work.layer_kinds(bad)
+
+
+def _windowed(dm, window):
+    kind = dm["layer_kinds"][0]
+    full = dict(kind, count=1)
+    band = dict(kind, count=1, attention=dict(kind["attention"],
+                                              window=window))
+    return dict(dm, layer_kinds=[full, band])
+
+
+def test_windowed_kind_reads_only_its_window():
+    """A windowed layer counts each slot's context capped at the
+    window, from the run record's counter; without it, nothing."""
+    dm = _windowed(harness.ref_dims(_fixture()), 16)
+    assert work.decode_step(dm, 5, 300, False) is None
+    assert work.decode_attention(dm, 5, 300) is None
+    full = harness.ref_dims(_fixture())
+    one = 2 * 4 * 16 * 2                        # K and V bytes, one layer
+    a = work.decode_attention(dm, 5, 300, windowed=80)
+    assert a["kv_bytes"] == 300 * one + 80 * one
+    assert a["flops"] == pytest.approx(4 * 4 * 16 * (300 + 80))
+    w = work.decode_step(dm, 5, 300, False, windowed=80)
+    f = work.decode_step(full, 5, 300, False)
+    assert f["bytes"] - w["bytes"] == pytest.approx((300 - 80) * one)
+    st = {"steps": 4, "active_slot_steps": 20, "context_tokens": 1200}
+    assert work.per_step(st) == {"tokens": 5, "context": 300,
+                                 "windowed": None}
+    st[work.WINDOWED_CONTEXT] = 320
+    assert work.per_step(st)["windowed"] == 80
+    # prefill: each of T queries sees at most W keys
+    T, W = 64, 16
+    assert work.unit_forward(dm, 1, T) * 2 == pytest.approx(
+        2 * (2 * T * work._per_token(work.layer_kinds(dm)[0]))
+        + 2 * T * T * 64 + 2 * (2 * W * T - W * W) * 64)
+    with pytest.raises(ValueError, match="one window"):
+        work.layer_kinds(dict(dm, layer_kinds=[
+            dm["layer_kinds"][1], dict(dm["layer_kinds"][1], attention=dict(
+                dm["layer_kinds"][1]["attention"], window=32))]))
+
+
+@pytest.mark.parametrize("reader", ["serve.decode_mfu",
+                                    "paged_decode_attn_roofline"])
+def test_step_readers_need_the_windowed_count(reader):
+    dm = _windowed(harness.ref_dims(_fixture()), 16)
+    trace = {"program_count": lambda name: 4,
+             "program_time": lambda name: 0.4,
+             "kernel_time": lambda prog, *k: 0.1}
+    stats = {"steps": 4, "active_slot_steps": 20, "context_tokens": 1200}
+    ctx = {"trace": trace, "dims": dm, "peaks": {"bf16_flops": 197e12,
+                                                 "hbm_bytes_per_s": 819e9},
+           "run": {"stats": stats, "sparse": {"mode": "dense"}}}
+    read = harness.metric_reader(reader).read
+    assert read(ctx) is None
+    stats[work.WINDOWED_CONTEXT] = 320
+    assert read(ctx) > 0
+
+
+def test_layout_fixture_serves_on_cpu():
+    """Through the unchanged set-up and window of a serve cell: the
+    batcher serves every request of the window to its length."""
+    from lib import serve_cell
+    conf = _fixture()
+    cell = _serve_cell(weights="2:4-magnitude", sparse="dense", rate=20.0)
+    st = serve_cell.setup(cell, conf, 2 ** 31 + 9)
+    rec = serve_cell.window(st, cell, TINY_CHAT, 2 ** 31 + 9, 0.3)
+    assert rec["offered"] == 6 and len(rec["results"]) == 6
+    for r, q in rec["results"]:
+        assert len(r.tokens) == q["max_new"]
+        assert all(0 <= t < 256 for t in r.tokens)
+    m = serve_cell.metrics(rec)
+    assert m["failed"] == 0 and m["tokens"] == sum(
+        q["max_new"] for _, q in rec["results"])
+
+
+# ---------------------------------------------------------------------------
 # trace reduction, on a trace recorded on the chip
 # ---------------------------------------------------------------------------
 TESTDATA = os.path.join(BENCH, "testdata")
 
 
-def _testdata_trace():
+#: recorded traces: one from before the program wrote spans or had the
+#: lane-dense decode kernel, and a ``--trace 1`` run of the batch cell
+RECORDED = ["serve_packed", "serve_batch"]
+
+
+def _testdata_trace(name="serve_packed"):
     import gzip
     from jax.profiler import ProfileData
     from lib import trace
-    with gzip.open(os.path.join(TESTDATA, "serve_packed.xplane.pb.gz")) as f:
+    with gzip.open(os.path.join(TESTDATA, f"{name}.xplane.pb.gz")) as f:
         planes = ProfileData.from_serialized_xspace(f.read()).planes
     return trace.reduce_planes(planes)
 
 
-def test_trace_reduction_recorded_chip_trace():
-    want = harness.read_json(TESTDATA, "serve_packed.expected.json")
-    t = _testdata_trace()
+@pytest.mark.parametrize("name", RECORDED)
+def test_trace_reduction_recorded_chip_trace(name):
+    want = harness.read_json(TESTDATA, f"{name}.expected.json")
+    t = _testdata_trace(name)
     assert t is not None
     assert t["window_s"] == pytest.approx(want["window_s"], rel=1e-9)
     assert t["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
     assert 0 < t["busy_s"] < t["window_s"]
-    for name, sec in want["programs"].items():
-        assert t["programs"][name] == pytest.approx(sec, rel=1e-9)
+    for name_, sec in want["programs"].items():
+        assert t["programs"][name_] == pytest.approx(sec, rel=1e-9)
     assert t["program_count"]("step") == want["program_counts"]["step"]
     for kern, sec in want["step_kernels"].items():
         assert t["kernel_time"]("step", kern) == pytest.approx(sec, rel=1e-9)
@@ -199,26 +626,35 @@ def test_trace_reduction_recorded_chip_trace():
     assert sum(s for _, s in t["breakdown"]["idle_gaps"]) == pytest.approx(
         t["window_s"] - t["busy_s"], rel=1e-6)
     assert not any(" while(" in n for n, _ in t["breakdown"]["device_ops"])
+    spans = want.get("program_spans", {})
+    assert set(t["program_spans"]) == set(spans)
+    for n, sp in spans.items():
+        assert t["program_spans"][n]["count"] == sp["count"]
+        assert t["program_spans"][n]["seconds"] == pytest.approx(
+            sp["seconds"], rel=1e-9)
+    assert sum(t["idle_by_program_span"].values()) == pytest.approx(
+        t["window_s"] - t["busy_s"], rel=1e-6)
 
 
 def test_readers_on_recorded_chip_trace():
     """Every per-layer reader of the batch cell finds its number in the
-    recorded trace, and no share of a roofline or peak passes 100 %."""
-    t = _testdata_trace()
+    recorded ``--trace 1`` run's trace and run record, and reads what
+    that run printed on the chip; no share of a roofline or a peak
+    passes 100 %."""
+    want = harness.read_json(TESTDATA, "serve_batch.expected.json")
+    t = _testdata_trace("serve_batch")
     conf = harness.read_json(BENCH, "configs", "opt-125m.json")
     dm = harness.ref_dims(conf)
-    steps = t["program_count"]("step")
-    run = {"stats": {"steps": steps, "active_slot_steps": 8 * steps,
-                     "context_tokens": 8 * 300 * steps},
-           "cell": harness.read_json(BENCH, "workloads", f"{BATCH}.json"),
-           "sparse": {"mode": "packed"}, "dims": dm}
+    run = dict(want["run"], dims=dm, cell=harness.read_json(
+        BENCH, "workloads", f"{BATCH}.json"))
     ctx = {"trace": t, "run": run, "dims": dm,
            "peaks": harness.peaks("TPU v5 lite")}
     metrics = [m for m in harness.benchmark()["per_layer"]
                if BATCH in m["workloads"]]
     out = harness.per_layer(BATCH, metrics, ctx)
-    assert set(out) == {m["name"] for m in metrics}
+    assert set(out) == {m["name"] for m in metrics} == set(want["metrics"])
     for name, m in out.items():
+        assert m["value"] == pytest.approx(want["metrics"][name], rel=1e-9)
         assert m["value"] > 0, name
         if m["unit"] == "%":
             assert m["value"] <= 100, name
@@ -274,6 +710,32 @@ def test_trace_reduction_by_hand():
     assert gaps == pytest.approx({"bench.decode_tick": 50e-9,
                                   "bench.admit": 400e-9,
                                   "outside the harness's spans": 150e-9})
+
+
+def test_reader_declares_a_kernel(tmp_path, monkeypatch):
+    """A reader file with a module constant ``KERNELS`` adds its kernel
+    to the reduction, with no edit of ``trace.py``; the readers in the
+    tree declare none beyond the built-in four."""
+    from lib import trace
+    assert set(trace.kernel_patterns()) == set(trace.KERNELS)
+    (tmp_path / "grouped_mm_roofline.py").write_text(
+        '"""A reader."""\nKERNELS = ("grouped_mm",)\n\n\n'
+        'def read(ctx):\n    return None\n')
+    (tmp_path / "other.py").write_text("KERNEL = ('not_one',)\n")
+    host = _Plane("/host:CPU", [("python", [("bench.window", 0, 1000)])])
+    dev = _Plane("/device:TPU:0", [
+        ("XLA Modules", [("jit_step(1)", 0, 1000)]),
+        ("XLA Ops", [("%grouped_mm.12 = bf16[8] custom-call(x)", 100, 300),
+                     ("%spmm24.3 = bf16[8] custom-call(x)", 500, 100)])])
+    assert trace.reduce_planes([host, dev])["kernel_time"](
+        "step", "grouped_mm") is None
+    monkeypatch.setattr(trace, "METRICS_DIR", str(tmp_path))
+    assert trace.reader_kernels() == ("grouped_mm",)
+    t = trace.reduce_planes([host, dev])
+    assert t["kernel_time"]("step", "grouped_mm") == pytest.approx(300e-9)
+    assert t["kernel_time"]("step", "spmm24") == pytest.approx(100e-9)
+    assert dict(t["breakdown"]["device_ops"])[
+        "grouped_mm.12 (grouped_mm)"] == pytest.approx(300e-9)
 
 
 def test_trace_union_and_labels():

@@ -2,9 +2,9 @@
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
-They cover ``lib/program_spans.reduce_planes`` on synthetic planes and
-on the trace recorded on the chip (which holds no program span), and
-the readers ``serve.host_ms_per_tick`` and
+They cover the program spans of ``lib/trace.reduce_planes`` on
+synthetic planes and on a trace recorded on the chip before the program
+wrote any spans, and the readers ``serve.host_ms_per_tick`` and
 ``serve.idle_in_host_ms_per_tick`` on run records with and without what
 they read.
 """
@@ -58,7 +58,7 @@ def _planes():
 def test_idle_by_program_span_by_hand():
     """Each idle gap goes to the innermost program span open at its
     middle; the bench's own spans are not program spans."""
-    t = program_spans.reduce_planes(_planes())
+    t = trace.reduce_planes(_planes())
     # busy [250, 550) and [1000, 1100); gaps [100, 250) middle 175: the
     # prepare; [550, 1000) middle 775: the admit
     assert t["idle_by_program_span"] == pytest.approx(
@@ -79,45 +79,45 @@ def test_idle_outside_program_spans():
         ("bench.window", 0, 100), ("serve.tick", 0, 30)])])
     dev = _Plane("/device:TPU:0", [("XLA Ops", [("%a = f32[] add(x)",
                                                  40, 20)])])
-    t = program_spans.reduce_planes([host, dev])
+    t = trace.reduce_planes([host, dev])
     assert t["idle_by_program_span"] == pytest.approx(
         {"serve.tick": 40e-9, program_spans.OUTSIDE: 40e-9})
 
 
 def test_innermost_span_at_points():
     spans = [("a", 0, 10), ("b", 2, 4), ("c", 6, 8), ("d", 12, 20)]
-    assert program_spans._innermost(spans, [1, 3, 5, 7, 11, 15, 25]) == \
+    assert trace._innermost(spans, [1, 3, 5, 7, 11, 15, 25]) == \
         ["a", "b", "a", "c", None, "d", None]
 
 
 def test_recorded_chip_trace_has_no_program_spans():
-    """The recorded trace predates the annotations: both keys are empty,
-    and the idle reader reports nothing on it."""
+    """The older recorded trace predates the annotations: both keys are
+    empty, and the idle reader reports nothing on it."""
     from jax.profiler import ProfileData
     with gzip.open(os.path.join(TESTDATA, "serve_packed.xplane.pb.gz")) as f:
         planes = list(ProfileData.from_serialized_xspace(f.read()).planes)
-    t = program_spans.reduce_planes(planes)
+    t = trace.reduce_planes(planes)
     assert t["program_spans"] == {}
-    base = trace.reduce_planes(planes)
     assert t["idle_by_program_span"] == pytest.approx(
-        {program_spans.OUTSIDE: base["window_s"] - base["busy_s"]})
-    ctx = {"trace": base, "program_spans": None}
+        {program_spans.OUTSIDE: t["window_s"] - t["busy_s"]})
+    assert program_spans.read({"trace": t}) is None
+    ctx = {"trace": t}
     reader = harness.metric_reader("serve.idle_in_host_ms_per_tick")
     assert reader.read(ctx) is None
 
 
 def test_idle_reader_per_decode_step(capsys):
-    ctx = {"trace": {"program_count": lambda name: 2 if name == "step"
-                     else 0},
-           "program_spans": program_spans.reduce_planes(_planes())}
+    t = trace.reduce_planes(_planes())
+    ctx = {"trace": dict(t, program_count=lambda name: 2 if name == "step"
+                         else 0)}
     reader = harness.metric_reader("serve.idle_in_host_ms_per_tick")
     # prepare 150 ns and admit 450 ns of idle, over 2 steps
     assert reader.read(ctx) == pytest.approx(1e3 * 600e-9 / 2)
     assert "serve.admit" in capsys.readouterr().err
 
 
-def test_idle_reader_without_a_trace_on_disk(tmp_path, monkeypatch):
-    monkeypatch.setattr(program_spans, "TRACE_DIR", str(tmp_path))
+def test_idle_reader_without_a_trace_on_disk():
+    """A reduction without program spans in it."""
     ctx = {"trace": {"program_count": lambda name: 5}}
     reader = harness.metric_reader("serve.idle_in_host_ms_per_tick")
     assert reader.read(ctx) is None
